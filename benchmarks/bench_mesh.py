@@ -4,6 +4,9 @@ sharded-vs-default compress wall-clock, and gradient-exchange wire bytes.
 All mesh work runs in ONE child subprocess with a forced 8-device host
 platform (the device count is locked at first jax init, so the parent
 process — which may already hold a 1-device runtime — cannot host it).
+The forced devices are CPU slices, so the benchmark runs only where the
+parent's backend is the CPU: on an accelerator the parent holds the
+chips, and ``run`` returns None without starting a child.
 The child asserts the bit-identity gates FIRST (P=1 DP fit bitwise the
 scan fit; sharded-engine container byte-identical to the default engine;
 parts-mode latent packing byte-identical to full-array packing) and only
@@ -181,9 +184,17 @@ def _child(quick: bool) -> dict:
 # ---------------------------------------------------------------------------
 # parent: spawn the forced-mesh child, persist the summary
 # ---------------------------------------------------------------------------
-def run(quick: bool = True) -> dict:
+def run(quick: bool = True) -> "dict | None":
+    import jax
+
+    if jax.default_backend() != "cpu":
+        print(f"bench_mesh: skipped, the backend is "
+              f"{jax.default_backend()!r} and the forced host devices need "
+              f"the CPU")
+        return None
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(_REPO, "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
     cmd = [sys.executable, "-m", "benchmarks.bench_mesh", "--child"]
@@ -217,6 +228,8 @@ def main() -> None:
         print(_SENTINEL + json.dumps(summary))
         return
     summary = run(quick="--full" not in sys.argv)
+    if summary is None:
+        return
     best = max(summary["dp_fit"]["per_device_count"],
                key=lambda c: c["steps_per_s"])
     print(f"bench_mesh: gates {summary['gates']}; best DP fit "

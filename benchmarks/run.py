@@ -5,7 +5,7 @@ writes detailed CSVs under results/bench/.
 
   PYTHONPATH=src python -m benchmarks.run           # quick mode (default)
   PYTHONPATH=src python -m benchmarks.run --quick   # same, explicit
-  PYTHONPATH=src python -m benchmarks.run --full    # paper-scale surrogate
+  PYTHONPATH=src python -m benchmarks.run --full    # 16x24x120x120 surrogate
 
 Exits nonzero if any benchmark's internal assertion fails — in particular
 the bit-identity gates (fused decompress vs the retained pre-change decode,
@@ -34,7 +34,7 @@ def _timeit(fn, *args, repeat=3, **kw):
 
 def bench_kernels(rows):
     """Kernel micro-timings (CPU interpret mode — correctness path; TPU
-    timings come from the roofline analysis, not wall clock here)."""
+    timings come only from a chip run, never from here)."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ref
@@ -267,6 +267,9 @@ def bench_mesh_scaling(rows, full=False):
     from benchmarks import bench_mesh
 
     summary = bench_mesh.run(quick=not full)
+    if summary is None:
+        rows.append(("mesh_dp_fit", 0.0, "skipped:forced-host-devices-need-cpu"))
+        return
     best = max(summary["dp_fit"]["per_device_count"],
                key=lambda c: c["steps_per_s"])
     rows.append((
@@ -322,7 +325,11 @@ def bench_sz(rows):
 
 
 def main() -> None:
-    # --quick (the default) runs the small surrogates; --full paper-scale
+    from repro import compile_cache
+
+    compile_cache.configure(os.path.join(os.path.dirname(__file__), ".."))
+    # --quick (the default) runs the small surrogates; --full the
+    # 16x24x120x120 surrogate
     full = "--full" in sys.argv and "--quick" not in sys.argv
     rows: list[tuple] = []
     failures: list[str] = []
@@ -364,19 +371,6 @@ def main() -> None:
             bench_compression.run)
     guarded("bench_qoi", timed, "bench_qoi", bench_qoi.run)
     guarded("bench_gradcomp", timed, "bench_gradcomp", bench_gradcomp.run)
-
-    # roofline summary if dry-run artifacts exist
-    try:
-        from benchmarks import roofline
-
-        rrows = roofline.analyze()
-        if rrows:
-            worst = min(rrows, key=lambda r: r["roofline_frac"])
-            rows.append(("roofline_cells", float(len(rrows)),
-                         f"worst={worst['arch']}/{worst['shape']}"
-                         f"@{worst['roofline_frac']:.3f}"))
-    except Exception as e:  # noqa: BLE001
-        rows.append(("roofline_cells", 0.0, f"unavailable:{e!r}"))
 
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:

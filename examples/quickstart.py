@@ -225,16 +225,28 @@ def main():
     #    and streaming ingest that lands each chunk straight in a
     #    row-sharded device buffer, so each device holds only NB/P block
     #    rows and the full normalized field never exists on host. The
-    #    device count is locked at first jax init, so the demo runs in a
-    #    subprocess with 8 forced host devices; it prints the per-device
-    #    ingest memory high-water against the single-device total and the
-    #    sharded-compress NRMSE (container byte-identity with the
-    #    single-device engine is asserted in tier-1 and in
-    #    benchmarks/bench_mesh.py before any perf number).
+    #    demo prints the per-device ingest memory high-water against the
+    #    single-device total and the sharded-compress NRMSE (container
+    #    byte-identity with the single-device engine is asserted in
+    #    tier-1 and in benchmarks/bench_mesh.py before any perf number).
+    #    On CPU the device count is locked at first jax init, so the demo
+    #    runs in a child process with 8 forced host devices. On an
+    #    accelerator this process already holds the devices and a child
+    #    could not reach them, so the demo runs here, on the real ones.
+    import jax
+
+    if jax.default_backend() != "cpu":
+        from repro.parallel import mesh_fit
+
+        print(f"\nmesh-sharded fit ({len(jax.devices())} "
+              f"{jax.default_backend()} device(s), in this process):")
+        mesh_fit.demo()
+        return
     import subprocess
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(__file__), "..", "src"
     ) + os.pathsep + env.get("PYTHONPATH", "")
@@ -243,9 +255,8 @@ def main():
         capture_output=True, text=True, env=env, timeout=600,
     )
     assert mesh_demo.returncode == 0, mesh_demo.stderr
-    print("\nmesh-sharded fit (8 forced host devices):")
+    print("\nmesh-sharded fit (8 forced host devices, child process):")
     print(mesh_demo.stdout.strip())
-
 
 if __name__ == "__main__":
     main()

@@ -113,6 +113,7 @@ def _fused_reconstruct(rt, artifact: CompressedArtifact) -> np.ndarray:
     vecs_dev = _fused_vecs(
         rt, artifact.ae_params, artifact.corr_params,
         _latents32(artifact.latent_q, artifact.latent_bin),
+        row0=0, n_rows=artifact.latent_q.shape[0],
     )
     return _apply_guarantees_and_finalize(vecs_dev, artifact)
 
@@ -176,8 +177,10 @@ def decompress(blob: bytes, *, species=None, time_range=None,
     ``species`` (an index or a sequence of indices) and/or ``time_range``
     (a half-open ``(t0, t1)`` frame window) select a slice to decode
     randomly-accessed: only the requested guarantee streams are parsed and
-    entropy-decoded, the fused NN decode covers only the block rows of the
-    window — and on a v3+ (time-sharded) container only the latent shards
+    entropy-decoded, the fused NN decode covers only the chunks of the
+    container's fixed decode grid (``runtime._FUSED_CHUNK`` block rows)
+    that the window touches — and on a v3+ (time-sharded) container only
+    the latent shards
     covering the window entropy-decode, making a window query O(window)
     end to end — with the result bitwise equal to slicing a full decode:
     ``decompress(b, species=s, time_range=(t0, t1))
@@ -223,6 +226,7 @@ def decompress(blob: bytes, *, species=None, time_range=None,
         vecs_dev = _fused_vecs(
             head.runtime, head.ae_params, head.corr_params,
             _latents32(head.latents.full(), head.latent_bin),
+            row0=0, n_rows=head.nb,
         )
         # the guarantee streams entropy-decode while the dispatched NN runs
         artifact = _finish_artifact(head)

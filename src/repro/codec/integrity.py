@@ -215,7 +215,8 @@ def salvage_decompress(blob: bytes, *, species=None, time_range=None):
         failures.append(IntegrityFailure.from_error(e))
     lat32 = runtime._latents32(rows, head.latent_bin)
     vecs_dev = runtime._fused_vecs(
-        head.runtime, head.ae_params, head.corr_params, lat32
+        head.runtime, head.ae_params, head.corr_params, lat32,
+        row0=b0, n_rows=head.nb,
     )
 
     # --- guarantees: per-species quarantine ------------------------------

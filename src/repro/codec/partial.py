@@ -344,13 +344,15 @@ class PartialDecoder:
         plan = plan_slice(head, species, time_range)
 
         # fused NN decode over the window's block rows only (async
-        # dispatch; rows are independent, so the slice is bit-transparent).
-        # v3: only the latent shards covering [b0, b1) entropy-decode.
+        # dispatch, on the container's fixed chunk grid, so the slice is
+        # bit-transparent). v3: only the latent shards covering [b0, b1)
+        # entropy-decode.
         lat32 = _latents32(
             head.latents.rows(plan.b0, plan.b1), head.latent_bin
         )
         vecs_dev = _fused_vecs(
-            head.runtime, head.ae_params, head.corr_params, lat32
+            head.runtime, head.ae_params, head.corr_params, lat32,
+            row0=plan.b0, n_rows=head.nb,
         )
 
         import jax.numpy as jnp

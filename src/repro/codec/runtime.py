@@ -613,32 +613,42 @@ def _latents32(latent_q: np.ndarray, latent_bin: float) -> np.ndarray:
     return dequantize(latent_q, latent_bin).astype(np.float32)
 
 
-_FUSED_CHUNK = 4096  # blocks per fused-decode dispatch: bounds peak
-# activation memory at paper scale (the quick surrogates fit in one chunk)
-# without re-tracing — the tail chunk is padded to the fixed shape
+# Blocks per fused-decode dispatch, and the grid every container decodes
+# on. It is part of the format: the encoder holds its guarantee against the
+# reconstruction decoded on this grid, so changing it changes the decoded
+# bits of every container already written. 512 is also the staged
+# reference path's batch (`artifact._batched`).
+_FUSED_CHUNK = 512
 
 
 def _fused_vecs(rt: _DecodeRuntime, ae_params, corr_params,
-                lat32: np.ndarray):
-    """Run the fused NN decode over fixed-size block chunks.
+                lat32: np.ndarray, *, row0: int, n_rows: int):
+    """Run the fused NN decode over block rows ``[row0, row0 + len(lat32))``
+    of a container with ``n_rows`` rows.
 
-    Dispatches are asynchronous, so callers can overlap host work with the
-    whole chunk sequence; results are concatenated on device. Chunking is
-    row-wise and therefore bit-transparent.
+    The rows decode on one grid of chunks per container, ``[k C, (k+1) C)``
+    with ``C = min(_FUSED_CHUNK, n_rows)``, each dispatched at the full
+    chunk shape with every row at its own offset (absent rows are zero
+    latents). XLA picks kernels, and so summation orders, by shape: on
+    XLA:CPU a dense layer over a few dozen rows rounds differently from
+    the same layer over a few hundred. The fixed grid makes a row's bits
+    independent of the window that asked for it, so a window decode is
+    bitwise the slice of the full decode, and it decodes at most one
+    partial chunk beyond the window at each end. Dispatches are
+    asynchronous; results concatenate on device.
     """
     import jax.numpy as jnp
 
     n = lat32.shape[0]
-    if n <= _FUSED_CHUNK:
-        return rt.jit_fused(ae_params, corr_params, lat32)
+    chunk = min(_FUSED_CHUNK, int(n_rows))
+    r1 = row0 + n
     outs = []
-    for i in range(0, n, _FUSED_CHUNK):
-        chunk = lat32[i : i + _FUSED_CHUNK]
-        pad = _FUSED_CHUNK - chunk.shape[0]
-        if pad:
-            chunk = np.concatenate(
-                [chunk, np.repeat(chunk[-1:], pad, axis=0)]
-            )
-        out = rt.jit_fused(ae_params, corr_params, chunk)
-        outs.append(out[:, : out.shape[1] - pad] if pad else out)
-    return jnp.concatenate(outs, axis=1)  # (S, NB, D) along blocks
+    for c0 in range(row0 - row0 % chunk, r1, chunk):
+        lo, hi = max(c0, row0), min(c0 + chunk, r1)
+        part = lat32[lo - row0 : hi - row0]
+        if hi - lo < chunk:
+            part = np.zeros((chunk,) + lat32.shape[1:], np.float32)
+            part[lo - c0 : hi - c0] = lat32[lo - row0 : hi - row0]
+        out = rt.jit_fused(ae_params, corr_params, part)
+        outs.append(out if hi - lo == chunk else out[:, lo - c0 : hi - c0])
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)

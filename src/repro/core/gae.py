@@ -34,7 +34,7 @@ bound:
   projection, ordering, and reconstruction tensors stay device-resident.
 * ``select(prepared, tau, coeff_bin)`` — the cheap per-error-bound pass:
   one jitted dispatch fuses quantization, the gain cumsum/cut (jnp ops
-  under ``enable_x64``), and the masked select-and-accumulate correction
+  under ``jax.enable_x64``), and the masked select-and-accumulate correction
   GEMM (``gbatc_select_accumulate``); the host then assembles the CSR
   artifact with vectorized ``nonzero``/``cumsum`` passes.
 
@@ -271,6 +271,22 @@ def _effective_bin(coeff_bin: float, tau: float, d: int) -> float:
     return float(min(coeff_bin, cap)) if coeff_bin > 0 else float(cap)
 
 
+def _fp32_margin(d: int) -> float:
+    """Part of tau, per unit of ||r||, that a cut on fp32 projections
+    leaves unused.
+
+    The MXU projection c' misses the exact c = U^T r by some e, and the
+    fp32 replay adds an error of the same order. Counted in c''s own frame
+    (its energy, not the exact ||r||^2), the real residual norm then
+    exceeds the counted one by at most ||e||, by the triangle inequality.
+    A length-D fp32 product is off by about sqrt(D) eps32 ||r|| in
+    practice and D^1.5 eps32 / 2 ||r|| at worst; D eps32 ||r|| lies well
+    above the first. The margin only makes a miss rare: ``select`` checks
+    every block's real residual and holds the bound where a cut fell short.
+    """
+    return float(d) * float(np.finfo(np.float32).eps)
+
+
 _POOL: Optional[ThreadPoolExecutor] = None
 
 
@@ -316,11 +332,11 @@ class PreparedGuarantee:
     norms2: np.ndarray  # (S, NB) float64 residual energies (host)
     basis: np.ndarray  # (S, D, D) float64 PCA bases (host, oracle-bitwise)
     inv_rank: np.ndarray  # (S, NB, D) int32 energy rank of each element (host)
-    coeffs: np.ndarray  # (S, NB, D) float64 projections (host mirror)
-    coeffs_sorted: np.ndarray  # (S, NB, D) float64, energy-descending per block
+    coeffs: np.ndarray  # (S, NB, D) engine.coeff_dtype projections (host mirror)
+    coeffs_sorted: np.ndarray  # (S, NB, D) coeff_dtype, energy-descending per block
     # device-resident tensors (jax arrays; None when a backend never reads them)
-    coeffs_dev: object  # (S, NB, D) float64 projections (jit selection backend)
-    coeffs_sorted_dev: object  # (S, NB, D) float64 (jit selection backend)
+    coeffs_dev: object  # (S, NB, D) coeff_dtype projections (jit selection backend)
+    coeffs_sorted_dev: object  # (S, NB, D) coeff_dtype (jit selection backend)
     inv_rank_dev: object  # (S, NB, D) int32 rank of each element
     norms2_dev: object  # (S, NB) float64
     x_rec_dev: object  # (S, NB, D) float32
@@ -332,7 +348,12 @@ class GuaranteeEngine:
 
     ``interpret`` defaults to True off-TPU (Pallas interpret mode); tile
     sizes default to one grid step per dispatch under interpret mode and to
-    TPU-friendly (1 species, 512 rows) tiles otherwise.
+    TPU-friendly (1 species, 512 rows) tiles otherwise. The projection
+    follows the kernels: fp64 under interpret mode, where the artifacts are
+    bit-identical to the numpy oracle, and fp32 on the MXU, which has no
+    fp64; a cut on fp32 coefficients is counted in their own energy frame
+    and aims :func:`_fp32_margin` below tau, and ``select`` then checks each
+    block's real residual in fp64 (:meth:`_hold_bound`).
 
     ``select_backend`` picks where the coefficient-selection math (the
     quantized-gain cumsum and its first crossing) runs:
@@ -378,6 +399,12 @@ class GuaranteeEngine:
         self._correct_jit = None
         self._apply_jit = None
 
+    @property
+    def coeff_dtype(self):
+        """The projection's dtype, set by the kernels' platform: fp64 under
+        interpret mode, fp32 on the MXU."""
+        return np.float64 if self.interpret else np.float32
+
     # -- jitted stages -------------------------------------------------
     def _kernel_opts(self):
         return dict(
@@ -410,9 +437,7 @@ class GuaranteeEngine:
                 x_rec, cqv32, inv_rank, m_eff, basis32, **opts
             )
 
-        def select_fn(
-            coeffs, coeffs_sorted, inv_rank, norms2, x_rec, basis32, tau2, bin_size
-        ):
+        def cut_fp64(coeffs_sorted, norms2, tau2, bin_size):
             # gains in energy-descending order (the sort itself is
             # tau-independent and lives in prepare); gains are >= 0, so the
             # first cumsum crossing IS the oracle's running-max crossing
@@ -420,11 +445,41 @@ class GuaranteeEngine:
             cqv_s = cq_s * bin_size
             gain = 2.0 * coeffs_sorted * cqv_s - cqv_s * cqv_s
             cum = jnp.cumsum(gain, axis=2)
-            target = norms2 - tau2
-            needs = norms2 > tau2
-            m = 1 + jnp.argmax(cum >= target[..., None], axis=2)
-            achieved = jnp.take_along_axis(cum, (m - 1)[..., None], axis=2)[..., 0]
-            m_eff = jnp.where(needs, m, 0).astype(jnp.int32)
+            m = 1 + jnp.argmax(cum >= (norms2 - tau2)[..., None], axis=2)
+            achieved = jnp.take_along_axis(cum, (m - 1)[..., None], axis=2)
+            return m, achieved[..., 0]
+
+        def cut_fp32(coeffs_sorted, norms2, tau2, bin_size):
+            # the energy each cut leaves, counted in the fp32 projections'
+            # own frame: the tail energy of the coefficients not kept plus
+            # the quantization error of those kept. Both are small near the
+            # cut, so fp32 sums hold them to ~D eps32 of tau^2, where a
+            # cumsum of gains would carry ~D eps32 of ||r||^2
+            cqv = (jnp.rint(coeffs_sorted / bin_size) * bin_size).astype(
+                jnp.float32)
+            tail = jnp.cumsum(jnp.square(coeffs_sorted)[..., ::-1], axis=2)
+            left = jnp.cumsum(jnp.square(coeffs_sorted - cqv), axis=2) + (
+                jnp.pad(tail[..., ::-1][..., 1:], ((0, 0), (0, 0), (0, 1))))
+            tau_cut = jnp.maximum(
+                jnp.sqrt(tau2)
+                - _fp32_margin(coeffs_sorted.shape[2]) * jnp.sqrt(norms2),
+                0.0,
+            )
+            # capped at the full correction, which the bin clamp holds
+            # within 0.9 tau
+            bound = jnp.maximum((tau_cut * tau_cut).astype(jnp.float32),
+                                left[..., -1])
+            m = 1 + jnp.argmax(left <= bound[..., None], axis=2)
+            left_m = jnp.take_along_axis(left, (m - 1)[..., None], axis=2)
+            # in the frame select() checks: the energy the cut removed
+            return m, norms2 - left_m[..., 0]
+
+        def select_fn(
+            coeffs, coeffs_sorted, inv_rank, norms2, x_rec, basis32, tau2, bin_size
+        ):
+            cut = cut_fp32 if coeffs_sorted.dtype == jnp.float32 else cut_fp64
+            m, achieved = cut(coeffs_sorted, norms2, tau2, bin_size)
+            m_eff = jnp.where(norms2 > tau2, m, 0).astype(jnp.int32)
             cq = jnp.rint(coeffs / bin_size)  # index-ordered ints (as f64)
             corrected = gbatc_select_accumulate(
                 x_rec, (cq * bin_size).astype(jnp.float32), inv_rank, m_eff,
@@ -477,8 +532,7 @@ class GuaranteeEngine:
         provenance: a stale ``reuse`` from different ``x`` is rejected by
         the caller contract (pipeline passes its one fitted ``vecs_orig``).
         """
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        import jax
 
         if self._project_jit is None:
             self._build_jits()
@@ -524,9 +578,11 @@ class GuaranteeEngine:
         # byte accounting bit-identical to it.
         basis_stale, _ = pca.pca_basis_stack(residual, executor=_pool())
 
-        with enable_x64():
-            residual_dev = self._stage(residual)
-            basis_dev = self._stage(basis_stale)
+        with jax.enable_x64(True):
+            residual_dev = self._stage(
+                residual.astype(self.coeff_dtype, copy=False))
+            basis_dev = self._stage(
+                basis_stale.astype(self.coeff_dtype, copy=False))
             coeffs_stale_dev = self._dispatch("project", residual_dev,
                                               basis_dev)
             # np.array, not asarray: a zero-copy view of the jax buffer has
@@ -561,7 +617,7 @@ class GuaranteeEngine:
         list(_pool().map(order_work, fresh))
         jit_backend = self.select_backend == "jit"
         full_recompute = coeffs is coeffs_stale
-        with enable_x64():
+        with jax.enable_x64(True):
             prepared = PreparedGuarantee(
                 shape=(s, nb, d),
                 x_ref=x,
@@ -572,10 +628,10 @@ class GuaranteeEngine:
                 coeffs=coeffs,
                 coeffs_sorted=coeffs_sorted,
                 # the host backend reads the host mirror only; keeping the
-                # device projection alive would pin S*NB*D fp64 for nothing.
-                # On a full recompute the projection is already device
-                # resident — re-uploading the host copy would waste a
-                # S*NB*D fp64 transfer on the accelerator path
+                # device projection alive would pin S*NB*D coefficients for
+                # nothing. On a full recompute the projection is already
+                # device resident — re-uploading the host copy would waste
+                # an S*NB*D transfer on the accelerator path
                 coeffs_dev=(
                     (coeffs_stale_dev if full_recompute
                      else self._stage(coeffs))
@@ -599,7 +655,7 @@ class GuaranteeEngine:
         coeff_bin: float = 0.0,
     ) -> tuple[np.ndarray, list[GuaranteeArtifact]]:
         """Apply Algorithm 1 at one error bound; returns (corrected, artifacts)."""
-        from jax.experimental import enable_x64
+        import jax
 
         if self._select_jit is None:
             self._build_jits()  # prep may come from a different engine
@@ -617,7 +673,7 @@ class GuaranteeEngine:
                 prep, needs, tau2, bin_size
             )
         else:
-            with enable_x64():
+            with jax.enable_x64(True):
                 corrected, cq, m_eff, achieved = self._dispatch(
                     "select",
                     prep.coeffs_dev,
@@ -629,7 +685,6 @@ class GuaranteeEngine:
                     np.float64(tau2),
                     np.float64(bin_size),
                 )
-                corrected = np.asarray(corrected)
                 cq = np.asarray(cq)
                 m_eff = np.asarray(m_eff)
                 achieved = np.asarray(achieved)
@@ -640,8 +695,49 @@ class GuaranteeEngine:
         if not np.all(achieved[needs] >= (target - slack)[needs]):
             raise AssertionError("guarantee violated — coefficient bin clamp failed")
 
+        if prep.coeffs.dtype == np.float32:
+            # replaces the select program's correction, whose in-program
+            # dequantization is not the one decode performs
+            corrected, m_eff = self._hold_bound(
+                prep, cq, m_eff, needs, tau2, bin_size
+            )
         arts = self._build_artifacts(prep, m_eff, cq, needs, bin_size, tau)
-        return corrected, arts
+        return np.asarray(corrected), arts
+
+    def _hold_bound(self, prep, cq, m_eff, needs, tau2, bin_size):
+        """Hold every block's real residual to tau after a cut on fp32
+        projections, whose count of the energy left can fall short of the
+        truth (see :func:`_fp32_margin`).
+
+        Replays the correction as decode does (host dequantization, the
+        same correction kernel), measures each block's residual against
+        the originals in fp64, and keeps one more coefficient, in energy
+        order, in any block over tau until none is. Blocks that need no
+        correction keep ``x_rec`` and so the residual ``prepare`` measured.
+        """
+        s, _, d = prep.shape
+        cqv32 = (cq * bin_size).astype(np.float32)  # as decode dequantizes
+
+        def err2(sidx):
+            r = prep.x_ref[sidx].astype(np.float64)
+            r -= corrected[sidx]
+            return np.sum(r * r, axis=1)
+
+        while True:
+            corrected = np.asarray(self._dispatch(
+                "correct", prep.x_rec_dev, cqv32, prep.inv_rank_dev, m_eff,
+                prep.basis32_dev,
+            ))
+            over = np.stack(list(_pool().map(err2, range(s)))) > tau2
+            over &= needs
+            if not over.any():
+                return corrected, m_eff
+            if np.any(m_eff[over] >= d):
+                raise AssertionError(
+                    "guarantee violated — a block misses tau with every "
+                    "coefficient kept"
+                )
+            m_eff = (m_eff + over).astype(np.int32)
 
     def _select_host(self, prep, needs, tau2, bin_size):
         """Host-numpy selection math + Pallas masked-correction dispatch.

@@ -417,7 +417,8 @@ class GBATCPipeline:
         rt = codec._runtime(self.cfg, self.n_species,
                             corr_params is not None)
         lat32 = np.ascontiguousarray(np.asarray(latents, dtype=np.float32))
-        out = codec._fused_vecs(rt, ae_params, corr_params, lat32)
+        out = codec._fused_vecs(rt, ae_params, corr_params, lat32,
+                                row0=0, n_rows=lat32.shape[0])
         return out if device else np.asarray(out)
 
     def _prepare_guarantee(self, latent_bin_rel: float, skip_correction: bool):

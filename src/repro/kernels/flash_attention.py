@@ -42,14 +42,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, causal, window, tk):
 
     def body(ki, carry):
         m, l, acc = carry
-        # leading dim indexed with a length-1 dslice: a bare int index does
-        # not discharge under interpret mode on current JAX
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(ki * bk, bk), slice(None)))[
-            0
-        ].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(ki * bk, bk), slice(None)))[
-            0
-        ].astype(jnp.float32)
+        k = k_ref[0, pl.ds(ki * bk, bk), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(ki * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)

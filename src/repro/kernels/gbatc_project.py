@@ -21,22 +21,31 @@ Two tiers of API:
   collapses it to a single step (species_per_tile=S, rows_per_tile=NB) so
   the interpreter overhead is paid once per call.
 
-``gbatc_select_accumulate`` fuses Algorithm 1's masked select-and-accumulate:
+``gbatc_select_accumulate`` is Algorithm 1's masked select-and-accumulate:
 given quantized coefficient values, each element's rank in the per-block
-energy order, and the per-block cut M, it forms the keep mask in-registers
-and applies the correction GEMM without ever materialising the masked
-coefficient tensor in HBM.
+energy order, and the per-block cut M, it applies the keep mask inside the
+XLA fusion that writes the kernel's lane-padded coefficient operand, so the
+mask costs no HBM pass of its own and the per-block cut never enters the
+kernel (an (S, NB) int32 block of (1, rows) is not a tile Mosaic accepts).
 
-All kernels compute in the dtype of their inputs (fp32 on the MXU path;
-fp64 under interpret mode, where the guarantee engine needs bit-stable
-quantization against the fp64 numpy oracle).
+All kernels compute in the dtype of their inputs: fp32 on the MXU, where
+every contraction asks for ``Precision.HIGHEST`` (the default would round
+the operands to bf16, far coarser than the error bound), and fp64 under
+interpret mode, where the guarantee engine needs bit-stable quantization
+against the fp64 numpy oracle. Mosaic has no fp64.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+# block-index zero for the index maps: a bare 0 traced under
+# jax.enable_x64 (the guarantee engine's select) is an i64 Mosaic refuses
+_Z = np.int32(0)
 
 
 def _pad_to(x, target, axis):
@@ -69,7 +78,8 @@ def _project_kernel(r_ref, u_ref, c_ref):
     r = r_ref[...]
     u = u_ref[...]
     c_ref[...] = jax.lax.dot_general(
-        r, u, (((1,), (0,)), ((), ())), preferred_element_type=c_ref.dtype
+        r, u, (((1,), (0,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=c_ref.dtype,
     ).astype(c_ref.dtype)
 
 
@@ -79,7 +89,8 @@ def _correct_kernel(x_ref, c_ref, m_ref, u_ref, o_ref):
     u = u_ref[...]
     o_ref[...] = (
         x + jax.lax.dot_general(
-            cm, u, (((1,), (1,)), ((), ())), preferred_element_type=o_ref.dtype
+            cm, u, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+            preferred_element_type=o_ref.dtype,
         ).astype(o_ref.dtype)
     )
 
@@ -163,21 +174,15 @@ _BATCH_DIMS_T = (((2,), (2,)), ((0,), (0,)))  # (s,n,k) @ (s,d,k) -> (s,n,d)
 
 def _project_batched_kernel(r_ref, u_ref, c_ref):
     c_ref[...] = jax.lax.dot_general(
-        r_ref[...], u_ref[...], _BATCH_DIMS, preferred_element_type=c_ref.dtype
+        r_ref[...], u_ref[...], _BATCH_DIMS, precision=_HIGHEST,
+        preferred_element_type=c_ref.dtype,
     ).astype(c_ref.dtype)
 
 
 def _correct_batched_kernel(x_ref, c_ref, u_ref, o_ref):
     o_ref[...] = x_ref[...] + jax.lax.dot_general(
-        c_ref[...], u_ref[...], _BATCH_DIMS_T, preferred_element_type=o_ref.dtype
-    ).astype(o_ref.dtype)
-
-
-def _select_accumulate_kernel(x_ref, c_ref, rank_ref, m_ref, u_ref, o_ref):
-    keep = rank_ref[...] < m_ref[...][..., None]
-    cm = c_ref[...] * keep.astype(c_ref.dtype)
-    o_ref[...] = x_ref[...] + jax.lax.dot_general(
-        cm, u_ref[...], _BATCH_DIMS_T, preferred_element_type=o_ref.dtype
+        c_ref[...], u_ref[...], _BATCH_DIMS_T, precision=_HIGHEST,
+        preferred_element_type=o_ref.dtype,
     ).astype(o_ref.dtype)
 
 
@@ -208,10 +213,10 @@ def gbatc_project_batched(
         _project_batched_kernel,
         grid=(sp // spt, rp // rpt),
         in_specs=[
-            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((spt, dp, dp), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, _Z)),
+            pl.BlockSpec((spt, dp, dp), lambda i, j: (i, _Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
+        out_specs=pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, _Z)),
         out_shape=jax.ShapeDtypeStruct((sp, rp, dp), dtype),
         interpret=interpret,
     )(r, u)
@@ -241,11 +246,11 @@ def gbatc_correct_batched(
         _correct_batched_kernel,
         grid=(sp // spt, rp // rpt),
         in_specs=[
-            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((spt, dp, dp), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, _Z)),
+            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, _Z)),
+            pl.BlockSpec((spt, dp, dp), lambda i, j: (i, _Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
+        out_specs=pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, _Z)),
         out_shape=jax.ShapeDtypeStruct((sp, rp, dp), dtype),
         interpret=interpret,
     )(x, c, u)
@@ -264,39 +269,14 @@ def gbatc_select_accumulate(
     interpret: bool = False,
     lane: int | None = None,
 ) -> jax.Array:
-    """Fused Algorithm-1 tail: x^G = x^R + (C * [rank < m]) @ U^T.
+    """Algorithm-1 tail: x^G = x^R + (C * [rank < m]) @ U^T.
 
-    The keep mask never leaves registers/VMEM — this is the "masked
-    select-and-accumulate" of the guarantee engine's decode-free hot path.
+    The keep mask rides the fusion that pads ``coeff_vals`` to the kernel's
+    tile shape, so the masked coefficients cost no HBM pass of their own.
     """
-    s, nb, d = x_rec.shape
-    dtype = jnp.result_type(x_rec.dtype, coeff_vals.dtype, basis.dtype)
-    dp = _lane(d, interpret, lane)
-    spt, rpt, sp, rp = _batched_tiles(s, nb, species_per_tile, rows_per_tile)
-    x = _pad_to(_pad_to(_pad_to(x_rec.astype(dtype), dp, 2), rp, 1), sp, 0)
-    c = _pad_to(_pad_to(_pad_to(coeff_vals.astype(dtype), dp, 2), rp, 1), sp, 0)
-    # pad ranks with a sentinel above any valid cut so padded lanes drop out
-    rk = jnp.pad(
-        rank.astype(jnp.int32),
-        [(0, sp - s), (0, rp - nb), (0, dp - d)],
-        constant_values=jnp.iinfo(jnp.int32).max,
+    keep = rank < m.astype(rank.dtype)[..., None]
+    return gbatc_correct_batched(
+        x_rec, coeff_vals * keep.astype(coeff_vals.dtype), basis,
+        species_per_tile=species_per_tile, rows_per_tile=rows_per_tile,
+        interpret=interpret, lane=lane,
     )
-    mm = _pad_to(m.astype(jnp.int32), rp, 1)
-    mm = _pad_to(mm, sp, 0)
-    u = _pad_to(_pad_to(_pad_to(basis.astype(dtype), dp, 1), dp, 2), sp, 0)
-
-    out = pl.pallas_call(
-        _select_accumulate_kernel,
-        grid=(sp // spt, rp // rpt),
-        in_specs=[
-            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((spt, rpt), lambda i, j: (i, j)),
-            pl.BlockSpec((spt, dp, dp), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((spt, rpt, dp), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((sp, rp, dp), dtype),
-        interpret=interpret,
-    )(x, c, rk, mm, u)
-    return out[:s, :nb, :d]

@@ -28,18 +28,16 @@ def _rglru_kernel(la_ref, b_ref, h0_ref, h_ref, hT_ref, *, chunk, t):
     )
 
     def body(ci, h0):
-        # length-1 dslice on the lead dim: a bare int index does not
-        # discharge under interpret mode on current JAX
-        sl = (pl.dslice(0, 1), pl.dslice(ci * chunk, chunk), slice(None))
-        la = pl.load(la_ref, sl)[0].astype(jnp.float32)  # (C, bw)
-        bb = pl.load(b_ref, sl)[0].astype(jnp.float32)
+        sl = (0, pl.ds(ci * chunk, chunk), slice(None))
+        la = la_ref[sl].astype(jnp.float32)  # (C, bw)
+        bb = b_ref[sl].astype(jnp.float32)
         cum = jnp.cumsum(la, axis=0)
         # pairwise decay weights e^{cum_t - cum_s} for s <= t
         pair = cum[:, None, :] - cum[None, :, :] + la[None, :, :] * 0.0
         # note: sum_{j=s+1..t} la_j = cum_t - cum_s
         w = jnp.where(tri[:, :, None], jnp.exp(pair), 0.0)  # (C, C, bw)
         h = jnp.exp(cum) * h0[None, :] + jnp.einsum("tsw,sw->tw", w, bb)
-        pl.store(h_ref, sl, h[None].astype(h_ref.dtype))
+        h_ref[sl] = h.astype(h_ref.dtype)
         return h[-1]
 
     hT = jax.lax.fori_loop(0, n_chunks, body, h0_ref[0].astype(jnp.float32))

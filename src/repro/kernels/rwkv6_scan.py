@@ -40,13 +40,11 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sT_ref,
     )
 
     def body(ci, s):
-        # length-1 dslice on the lead dim: a bare int index does not
-        # discharge under interpret mode on current JAX
-        sl = (pl.dslice(0, 1), pl.dslice(ci * chunk, chunk), slice(None))
-        r = pl.load(r_ref, sl)[0].astype(jnp.float32)  # (C, N)
-        k = pl.load(k_ref, sl)[0].astype(jnp.float32)
-        v = pl.load(v_ref, sl)[0].astype(jnp.float32)
-        lw = pl.load(lw_ref, sl)[0].astype(jnp.float32)
+        sl = (0, pl.ds(ci * chunk, chunk), slice(None))
+        r = r_ref[sl].astype(jnp.float32)  # (C, N)
+        k = k_ref[sl].astype(jnp.float32)
+        v = v_ref[sl].astype(jnp.float32)
+        lw = lw_ref[sl].astype(jnp.float32)
         cum = jnp.cumsum(lw, axis=0)  # inclusive prefix
         cum_prev = cum - lw  # exclusive prefix (cum_{t-1})
 
@@ -64,7 +62,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sT_ref,
             scores, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) + diag[:, None] * v
-        pl.store(o_ref, sl, out[None].astype(o_ref.dtype))
+        o_ref[sl] = out.astype(o_ref.dtype)
 
         # chunk-boundary state update (exponents <= 0)
         k_w = k * jnp.exp(cum[-1][None, :] - cum)  # (C, N)

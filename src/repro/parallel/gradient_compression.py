@@ -113,12 +113,10 @@ def quantized_all_reduce(x: jax.Array, mesh: Mesh, axis: str = "data",
     Wire volume: n*(P-1)/P bytes int8 + scales vs 2*n*(P-1)/P * 4 bytes for
     a ring all-reduce in fp32 -> ~8x reduction at 8 bits.
     """
-    from jax.experimental.shard_map import shard_map
-
     inner = functools.partial(quantized_psum, axis=axis, n_bits=n_bits,
                               block=block)
     # input sharded on dim 0 over `axis`; every shard returns the full sum
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )(x)

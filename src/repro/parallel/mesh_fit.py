@@ -67,7 +67,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import gae
@@ -193,11 +192,11 @@ def dp_scan_program(trainer, steps: int, n: int, bs: int, log_every: int,
         return params, state, losses
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             run_body, mesh=mesh,
             in_specs=(P(), P(), P()) + (P(DATA_AXIS),) * n_data,
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         ),
         donate_argnums=(0, 1),
     )
@@ -240,8 +239,9 @@ def dp_wire_report(params, n_devices: int, *, n_bits: int = 8,
 # ---------------------------------------------------------------------------
 #: per-kernel dispatch plan: for every positional arg and every output,
 #: ``(species_axis, row_axis)`` — ``None`` replicates (basis, scalars).
-#: ``x64`` mirrors the base engine's enable_x64 scopes: projection and
-#: selection math are fp64, correction/replay inputs are fp32/int32.
+#: ``x64`` mirrors the base engine's ``jax.enable_x64`` scopes: the
+#: projection (fp64 under interpret mode) and the fp64 selection math;
+#: correction/replay inputs are fp32/int32.
 _KERNEL_PLANS = {
     "project": dict(
         args=((0, 1), (0, None)), outs=((0, 1),), x64=True),
@@ -317,8 +317,6 @@ class ShardedGuaranteeEngine(gae.GuaranteeEngine):
         return np.asarray(arr)
 
     def _dispatch(self, kernel: str, *args):
-        from jax.experimental import enable_x64
-
         plan = _KERNEL_PLANS[kernel]
         fn = getattr(self, f"_{kernel}_jit")
         # problem extents from the first fully-chunked arg (S, NB, ...)
@@ -349,7 +347,7 @@ class ShardedGuaranteeEngine(gae.GuaranteeEngine):
             return pending
 
         if plan["x64"]:
-            with enable_x64():
+            with jax.enable_x64(True):
                 pending = stage_and_call()
                 fetched = [jax.tree.map(np.asarray, out) for out in pending]
         else:
@@ -459,7 +457,7 @@ class ShardedBlockStore:
 # ---------------------------------------------------------------------------
 # demo: per-device ingest memory vs single device (quickstart step 9)
 # ---------------------------------------------------------------------------
-def _demo() -> None:  # pragma: no cover - exercised via quickstart/driver
+def demo() -> None:  # pragma: no cover - exercised via the quickstart
     from repro.core.pipeline import GBATCPipeline, PipelineConfig
     from repro.data import s3d
 
@@ -477,7 +475,8 @@ def _demo() -> None:  # pragma: no cover - exercised via quickstart/driver
     }
     total = int(pipe._blocks.nbytes)
     peak = max(store_bytes.values())
-    print(f"mesh fit on {n_p} host device(s): normalized field "
+    print(f"mesh fit on {n_p} {jax.default_backend()} device(s): "
+          f"normalized field "
           f"{total} bytes total, per-device ingest high-water "
           f"{peak} bytes ({peak / total:.0%} of single-device)")
     rep = pipe.compress(target_nrmse=1e-3)
@@ -486,4 +485,4 @@ def _demo() -> None:  # pragma: no cover - exercised via quickstart/driver
 
 
 if __name__ == "__main__":  # pragma: no cover
-    _demo()
+    demo()

@@ -312,7 +312,8 @@ class DecodeService:
                 head.latents.rows(plan.b0, plan.b1), head.latent_bin
             )
             vecs = _fused_vecs(
-                head.runtime, head.ae_params, head.corr_params, lat32
+                head.runtime, head.ae_params, head.corr_params, lat32,
+                row0=plan.b0, n_rows=head.nb,
             )
             import jax.numpy as jnp
 
@@ -362,7 +363,8 @@ class DecodeService:
                     head.latents.rows(B0, B1), head.latent_bin
                 )
                 vecs_dev = _fused_vecs(
-                    head.runtime, head.ae_params, head.corr_params, lat32
+                    head.runtime, head.ae_params, head.corr_params, lat32,
+                    row0=B0, n_rows=head.nb,
                 )
             except ContainerFormatError:
                 # a latent shard in the union is corrupt — per-request

@@ -126,8 +126,6 @@ def scenario_dryrun_small_mesh():
             specs, st_specs, input_specs(cfg, shape))
         compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older JAX returns [dict]
-        cost = cost[0]
     assert cost.get("flops", 0) > 0
     coll = parse_collective_bytes(compiled.as_text())
     assert coll["total"] > 0, "sharded train step must communicate"

@@ -13,6 +13,57 @@ def _make_case(seed, nb=300, d=80, noise=0.1):
     return x, x_rec
 
 
+def _fp32_case(case):
+    rng = np.random.default_rng(11)
+    if case == "gaussian":
+        x = rng.normal(size=(3, 400, 80)).astype(np.float32)
+        return x, x + 0.1 * rng.normal(size=x.shape).astype(np.float32)
+    x = rng.standard_t(df=1.5, size=(3, 400, 80)).astype(np.float32)
+    return x, np.zeros_like(x)  # terrible reconstruction
+
+
+def _fp32_prepared(x, x_rec, rel_err=0.0):
+    """The state an MXU engine prepares: fp32 projections of the fp32
+    residual onto the fp32 basis, through the projection kernel, with
+    ``rel_err`` of each block's ||r|| added as noise when asked."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.gbatc_project import gbatc_project_batched
+
+    engine = gae.GuaranteeEngine(select_backend="jit")
+    prep = engine.prepare(x, x_rec)
+    r32 = (x.astype(np.float64) - x_rec).astype(np.float32)
+    c = np.array(gbatc_project_batched(
+        r32, prep.basis.astype(np.float32), interpret=True))
+    assert c.dtype == np.float32
+    if rel_err:
+        noise = np.random.default_rng(3).normal(size=c.shape)
+        noise *= rel_err * np.sqrt(prep.norms2 / c.shape[2])[..., None]
+        c += noise.astype(np.float32)
+    order = gae._stable_desc_order(c * c)
+    c_sorted = np.take_along_axis(c, order, axis=-1)
+    inv_rank = np.empty(c.shape, np.int32)
+    iota = np.broadcast_to(np.arange(c.shape[2], dtype=np.int32), c.shape)
+    np.put_along_axis(inv_rank, order, iota, axis=-1)
+    with jax.enable_x64(True):
+        return engine, dataclasses.replace(
+            prep, coeffs=c, coeffs_sorted=c_sorted, inv_rank=inv_rank,
+            coeffs_dev=jnp.asarray(c), coeffs_sorted_dev=jnp.asarray(c_sorted),
+            inv_rank_dev=jnp.asarray(inv_rank),
+        )
+
+
+def _assert_held_and_replayed(engine, x, x_rec, corrected, arts, tau):
+    """Every block within tau in fp64, with no slack, and the decode
+    replay bitwise what the engine checked."""
+    r = x.astype(np.float64) - corrected
+    assert np.sum(r * r, axis=2).max() <= tau * tau
+    np.testing.assert_array_equal(engine.apply_batched(x_rec, arts), corrected)
+
+
 class TestGuarantee:
     @pytest.mark.parametrize("tau", [0.1, 0.5, 2.0])
     @pytest.mark.parametrize("seed", [0, 1])
@@ -29,6 +80,38 @@ class TestGuarantee:
         x_rec = np.zeros_like(x)  # terrible reconstruction
         corrected, art = gae.guarantee(x, x_rec, 0.25)
         assert gae.verify_guarantee(x, corrected, 0.25)
+
+    @pytest.mark.parametrize("case", ["gaussian", "heavy_tailed"])
+    @pytest.mark.parametrize("tau", [0.05, 0.5])
+    def test_fp32_projection_holds_bound_every_block(self, case, tau):
+        """The MXU path projects in fp32; its cut must hold the real
+        residual, checked in fp64, and not only the coefficient-space
+        count, and decode must replay exactly what was checked."""
+        x, x_rec = _fp32_case(case)
+        engine, prep = _fp32_prepared(x, x_rec)
+        corrected, arts = engine.select(prep, tau)
+        _assert_held_and_replayed(engine, x, x_rec, corrected, arts, tau)
+
+    def test_fp32_cut_that_falls_short_is_held(self):
+        """A projection off by far more than fp32 rounding makes the cut
+        count too little energy left in some blocks; the engine must find
+        them by their real residual and keep more coefficients there."""
+        import jax
+
+        tau = 0.05
+        x, x_rec = _fp32_case("gaussian")
+        engine, prep = _fp32_prepared(x, x_rec, rel_err=1e-3)
+        bin_size = gae._effective_bin(0.0, tau, x.shape[2])
+        with jax.enable_x64(True):
+            cut_only = np.asarray(engine._select_jit(
+                prep.coeffs_dev, prep.coeffs_sorted_dev, prep.inv_rank_dev,
+                prep.norms2_dev, prep.x_rec_dev, prep.basis32_dev,
+                np.float64(tau * tau), np.float64(bin_size),
+            )[0])
+        r = x.astype(np.float64) - cut_only
+        assert np.sum(r * r, axis=2).max() > tau * tau
+        corrected, arts = engine.select(prep, tau)
+        _assert_held_and_replayed(engine, x, x_rec, corrected, arts, tau)
 
     def test_decode_replay_matches(self):
         x, x_rec = _make_case(2)

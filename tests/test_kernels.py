@@ -302,12 +302,11 @@ class TestGBATCBatchedKernels:
     def test_fp64_project_in_interpret(self):
         """The guarantee engine's selection math runs the projection in
         fp64 under interpret mode — dtype must be honored end to end."""
-        from jax.experimental import enable_x64
         rng = np.random.default_rng(0)
         r = rng.normal(size=(2, 50, 80))
         u = np.stack([np.linalg.qr(rng.normal(size=(80, 80)))[0]
                       for _ in range(2)])
-        with enable_x64():
+        with jax.enable_x64(True):
             c = gbatc_project_batched(jnp.asarray(r), jnp.asarray(u),
                                       interpret=True)
             assert c.dtype == jnp.float64

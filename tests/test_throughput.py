@@ -175,11 +175,15 @@ class TestTrainerEngine:
     def test_ae_loss_history_shape_and_finiteness(
         self, tiny_model, tiny_blocks
     ):
-        _, losses = self._fit(tiny_model, tiny_blocks, None)
+        params, losses = self._fit(tiny_model, tiny_blocks, None)
         assert losses.shape == (self.STEPS,)
         assert np.isfinite(losses).all()
-        # training decreases loss on average
-        assert losses[-3:].mean() < losses[:3].mean()
+        # training decreases the loss over the whole set (a dozen batch
+        # losses are too noisy to rank first against last)
+        loss = ae._ae_loss(tiny_model)
+        init = tiny_model.init(jax.random.PRNGKey(0))
+        assert float(loss(params, tiny_blocks)) < float(
+            loss(init, tiny_blocks))
 
     def test_correction_trainer_history_and_determinism(self):
         rng = np.random.default_rng(1)
@@ -268,16 +272,37 @@ class TestFusedDecode:
 
     def test_chunked_fused_decode_is_bit_transparent(self, fitted_blob,
                                                      monkeypatch):
-        """The fused NN decode chunks at _FUSED_CHUNK blocks to bound peak
-        activation memory at paper scale; chunking (including the padded
-        ragged tail) must not change a single bit."""
+        """The fused NN decode runs on a grid of _FUSED_CHUNK blocks. With a
+        chunk small enough that the container spans several chunks and the
+        last is ragged, the container must decode bitwise like the
+        encoder's own reconstruction, and every window decode, straddling
+        chunk edges, bitwise like the slice of the full decode."""
         from repro.codec import runtime as codec_runtime
 
-        _, _, _, blob = fitted_blob
-        full = codec.decompress(blob)
-        codec.clear_decode_cache()  # force a real re-decode under chunking
+        data = fitted_blob[0]
         monkeypatch.setattr(codec_runtime, "_FUSED_CHUNK", 48)
-        np.testing.assert_array_equal(codec.decompress(blob), full)
+        pipe = GBATCPipeline(fitted_blob[1].cfg, n_species=len(data))
+        pipe.fit(data)
+        rep = pipe.compress(target_nrmse=1e-3)
+        blob = rep.artifact.to_bytes()
+        assert pipe._latents.shape[0] % 48  # several chunks, a ragged last
+        full = codec.decompress(blob)
+        np.testing.assert_array_equal(full, pipe.decompress(rep.artifact))
+        t = full.shape[1]
+        for t0, t1 in ((0, 4), (1, 7), (4, t), (t - 3, t)):
+            np.testing.assert_array_equal(
+                codec.decompress(blob, time_range=(t0, t1)),
+                full[:, t0:t1],
+            )
+
+    def test_fused_chunk_is_a_format_constant(self):
+        """Containers record no decode grid: the encoder holds the bound
+        against the reconstruction decoded on this one, so every decoder
+        must use it. Changing it changes the decoded bits of every
+        container already written."""
+        from repro.codec import runtime as codec_runtime
+
+        assert codec_runtime._FUSED_CHUNK == 512
 
     def test_decompressed_meets_bound(self, fitted_blob):
         data, _, _, blob = fitted_blob
