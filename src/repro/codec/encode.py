@@ -28,6 +28,7 @@ import os
 import tempfile
 from typing import Optional
 
+from repro import tracing
 from repro.codec import families
 from repro.codec import format as wire
 from repro.codec.artifact import CompressedArtifact
@@ -36,6 +37,7 @@ from repro.core import container as container_format
 from repro.core.container import ContainerWriter
 
 
+@tracing.span("container.encode")
 def encode(artifact: CompressedArtifact,
            version: int = container_format.FORMAT_VERSION_FAMILY,
            *, shard_tgroups: Optional[int] = None) -> bytes:
@@ -73,9 +75,11 @@ def encode(artifact: CompressedArtifact,
         if tg < 1:
             raise ValueError(f"shard_tgroups must be >= 1, got {tg}")
         # through the artifact so a sweep's blobs share one packed stream
-        w.add("latent", artifact.sharded_latent_stream(tg * per_frame))
+        with tracing.span("container.encode.latent"):
+            w.add("latent", artifact.sharded_latent_stream(tg * per_frame))
     else:
-        w.add("latent", artifact.latent_blob())
+        with tracing.span("container.encode.latent"):
+            w.add("latent", artifact.latent_blob())
     packed = artifact._param_streams
     if packed is None:
         packed = pack_artifact_params(
@@ -84,12 +88,13 @@ def encode(artifact: CompressedArtifact,
     w.add("decoder", packed[0])
     if artifact.corr_params is not None:
         w.add("correction", packed[1])
-    if version >= container_format.FORMAT_VERSION_SELECTIVE:
-        w.add("guarantee",
-              wire.pack_guarantee_stream(artifact.species_guarantees))
-    else:
-        for sidx, g in enumerate(artifact.species_guarantees):
-            w.add(f"guarantee{sidx}", g.to_bytes())
+    with tracing.span("container.encode.guarantee"):
+        if version >= container_format.FORMAT_VERSION_SELECTIVE:
+            w.add("guarantee",
+                  wire.pack_guarantee_stream(artifact.species_guarantees))
+        else:
+            for sidx, g in enumerate(artifact.species_guarantees):
+                w.add(f"guarantee{sidx}", g.to_bytes())
     if version >= container_format.FORMAT_VERSION_INTEGRITY:
         # two-pass outer digest: the integrity payload's LENGTH is fixed
         # before its content (it depends only on stream count/names and

@@ -29,6 +29,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.codec import cache as tier_cache
 from repro.codec import families
 from repro.codec import format as wire
@@ -621,6 +622,7 @@ def _latents32(latent_q: np.ndarray, latent_bin: float) -> np.ndarray:
 _FUSED_CHUNK = 512
 
 
+@tracing.span("decode.fused")
 def _fused_vecs(rt: _DecodeRuntime, ae_params, corr_params,
                 lat32: np.ndarray, *, row0: int, n_rows: int):
     """Run the fused NN decode over block rows ``[row0, row0 + len(lat32))``

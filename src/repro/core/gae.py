@@ -59,6 +59,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core import container, entropy, index_coding, pca
 from repro.core.quantization import dequantize
 
@@ -512,6 +513,7 @@ class GuaranteeEngine:
         return getattr(self, f"_{kernel}_jit")(*args)
 
     # -- tau-independent stage -----------------------------------------
+    @tracing.span("guarantee.prepare")
     def prepare(
         self,
         x: np.ndarray,
@@ -569,16 +571,19 @@ class GuaranteeEngine:
         # float64 contract even for float64 reconstructions); only the
         # correction kernel input and fast-path output are float32
         full = len(stale) == s
-        x_rec_arr = np.asarray(x_rec)
-        residual = (x if full else x[stale]).astype(np.float64)
-        residual -= (x_rec_arr if full else x_rec_arr[stale]).astype(np.float64)
-        norms2_stale = np.sum(residual**2, axis=2)
+        with tracing.span("guarantee.prepare.residual"):
+            x_rec_arr = np.asarray(x_rec)
+            residual = (x if full else x[stale]).astype(np.float64)
+            residual -= (x_rec_arr if full
+                         else x_rec_arr[stale]).astype(np.float64)
+            norms2_stale = np.sum(residual**2, axis=2)
         # PCA on host numpy: the D x D eigh is tiny, and sharing the exact
         # gram/eigh path with the numpy oracle is what makes the engine's
         # byte accounting bit-identical to it.
-        basis_stale, _ = pca.pca_basis_stack(residual, executor=_pool())
+        with tracing.span("guarantee.prepare.pca"):
+            basis_stale, _ = pca.pca_basis_stack(residual, executor=_pool())
 
-        with jax.enable_x64(True):
+        with tracing.span("guarantee.prepare.project"), jax.enable_x64(True):
             residual_dev = self._stage(
                 residual.astype(self.coeff_dtype, copy=False))
             basis_dev = self._stage(
@@ -614,10 +619,11 @@ class GuaranteeEngine:
                 inv_rank[sidx], order, np.broadcast_to(iota, order.shape), axis=-1
             )
 
-        list(_pool().map(order_work, fresh))
+        with tracing.span("guarantee.prepare.order"):
+            list(_pool().map(order_work, fresh))
         jit_backend = self.select_backend == "jit"
         full_recompute = coeffs is coeffs_stale
-        with jax.enable_x64(True):
+        with tracing.span("guarantee.prepare.stage"), jax.enable_x64(True):
             prepared = PreparedGuarantee(
                 shape=(s, nb, d),
                 x_ref=x,
@@ -668,32 +674,34 @@ class GuaranteeEngine:
             return prep.x_rec32.astype(np.float32), arts
 
         bin_size = _effective_bin(coeff_bin, tau, d)
-        if self.select_backend == "host":
-            corrected, cq, m_eff, achieved = self._select_host(
-                prep, needs, tau2, bin_size
-            )
-        else:
-            with jax.enable_x64(True):
-                corrected, cq, m_eff, achieved = self._dispatch(
-                    "select",
-                    prep.coeffs_dev,
-                    prep.coeffs_sorted_dev,
-                    prep.inv_rank_dev,
-                    prep.norms2_dev,
-                    prep.x_rec_dev,
-                    prep.basis32_dev,
-                    np.float64(tau2),
-                    np.float64(bin_size),
+        with tracing.span("guarantee.select"):
+            if self.select_backend == "host":
+                corrected, cq, m_eff, achieved = self._select_host(
+                    prep, needs, tau2, bin_size
                 )
-                cq = np.asarray(cq)
-                m_eff = np.asarray(m_eff)
-                achieved = np.asarray(achieved)
+            else:
+                with jax.enable_x64(True):
+                    corrected, cq, m_eff, achieved = self._dispatch(
+                        "select",
+                        prep.coeffs_dev,
+                        prep.coeffs_sorted_dev,
+                        prep.inv_rank_dev,
+                        prep.norms2_dev,
+                        prep.x_rec_dev,
+                        prep.basis32_dev,
+                        np.float64(tau2),
+                        np.float64(bin_size),
+                    )
+                    cq = np.asarray(cq)
+                    m_eff = np.asarray(m_eff)
+                    achieved = np.asarray(achieved)
 
-        # Guaranteed by bin clamp, but assert rather than assume:
-        target = prep.norms2 - tau2
-        slack = 1e-9 * np.maximum(prep.norms2, 1.0)
-        if not np.all(achieved[needs] >= (target - slack)[needs]):
-            raise AssertionError("guarantee violated — coefficient bin clamp failed")
+            # Guaranteed by bin clamp, but assert rather than assume:
+            target = prep.norms2 - tau2
+            slack = 1e-9 * np.maximum(prep.norms2, 1.0)
+            if not np.all(achieved[needs] >= (target - slack)[needs]):
+                raise AssertionError(
+                    "guarantee violated — coefficient bin clamp failed")
 
         if prep.coeffs.dtype == np.float32:
             # replaces the select program's correction, whose in-program
@@ -716,28 +724,34 @@ class GuaranteeEngine:
         correction keep ``x_rec`` and so the residual ``prepare`` measured.
         """
         s, _, d = prep.shape
-        cqv32 = (cq * bin_size).astype(np.float32)  # as decode dequantizes
 
         def err2(sidx):
             r = prep.x_ref[sidx].astype(np.float64)
             r -= corrected[sidx]
             return np.sum(r * r, axis=1)
 
-        while True:
-            corrected = np.asarray(self._dispatch(
-                "correct", prep.x_rec_dev, cqv32, prep.inv_rank_dev, m_eff,
-                prep.basis32_dev,
-            ))
-            over = np.stack(list(_pool().map(err2, range(s)))) > tau2
-            over &= needs
-            if not over.any():
-                return corrected, m_eff
-            if np.any(m_eff[over] >= d):
-                raise AssertionError(
-                    "guarantee violated — a block misses tau with every "
-                    "coefficient kept"
-                )
-            m_eff = (m_eff + over).astype(np.int32)
+        with tracing.span("guarantee.hold_bound") as sp:
+            cqv32 = (cq * bin_size).astype(np.float32)  # as decode dequantizes
+            rounds = 0
+            while True:
+                rounds += 1
+                corrected = np.asarray(self._dispatch(
+                    "correct", prep.x_rec_dev, cqv32, prep.inv_rank_dev,
+                    m_eff, prep.basis32_dev,
+                ))
+                over = np.stack(list(_pool().map(err2, range(s)))) > tau2
+                over &= needs
+                if rounds == 1:
+                    sp.count(blocks_over=int(np.count_nonzero(over)))
+                if not over.any():
+                    sp.count(rounds=rounds)
+                    return corrected, m_eff
+                if np.any(m_eff[over] >= d):
+                    raise AssertionError(
+                        "guarantee violated — a block misses tau with every "
+                        "coefficient kept"
+                    )
+                m_eff = (m_eff + over).astype(np.int32)
 
     def _select_host(self, prep, needs, tau2, bin_size):
         """Host-numpy selection math + Pallas masked-correction dispatch.
@@ -799,6 +813,7 @@ class GuaranteeEngine:
         return corrected, cq, m_eff, achieved
 
     @staticmethod
+    @tracing.span("guarantee.artifacts")
     def _build_artifacts(prep, m_eff, cq, needs, bin_size, tau):
         """CSR artifact assembly: one flatnonzero pass per species, no
         per-block loops; species run on the shared thread pool."""

@@ -60,6 +60,7 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.codec.artifact import (  # noqa: F401  (canonical home moved;
     CompressedArtifact,  # re-exported so pipeline-layer imports keep working)
     _batched,
@@ -188,8 +189,9 @@ class GBATCPipeline:
     def fit(self, data: np.ndarray, verbose: bool = False) -> dict:
         """Train the AE (and correction net) once; returns training stats."""
         assert data.shape[0] == self.n_species
-        normed, mn, rngs = self._normalize(data)
-        blocks = blocking.to_blocks(normed, self.cfg.geometry)
+        with tracing.span("fit.blocks"):
+            normed, mn, rngs = self._normalize(data)
+            blocks = blocking.to_blocks(normed, self.cfg.geometry)
         return self._fit_blocks(
             blocks, mn, rngs, shape=tuple(data.shape),
             data_nbytes=data.nbytes, data=data, verbose=verbose,
@@ -270,9 +272,10 @@ class GBATCPipeline:
                 raise ValueError("loader yielded no chunks")
             return mn, mx, t_total, nbytes, spatial
 
-        mn, mx, t_total, nbytes, spatial = retry_with_backoff(
-            pass_ranges, **retry
-        )
+        with tracing.span("fit.blocks"):
+            mn, mx, t_total, nbytes, spatial = retry_with_backoff(
+                pass_ranges, **retry
+            )
         rngs = np.maximum(mx - mn, 1e-30)
         shape = (self.n_species, t_total, *spatial)
         blocking.check_divisible(shape, geom)
@@ -320,7 +323,8 @@ class GBATCPipeline:
                     row += part.shape[0]
                 return blocks
 
-        blocks = retry_with_backoff(pass_blocks, **retry)
+        with tracing.span("fit.blocks"):
+            blocks = retry_with_backoff(pass_blocks, **retry)
         return self._fit_blocks(
             blocks, mn.astype(np.float32), rngs.astype(np.float32),
             shape=shape, data_nbytes=nbytes, data=None, verbose=verbose,
@@ -341,47 +345,53 @@ class GBATCPipeline:
         cfg = self.cfg
         on_device = not isinstance(blocks, np.ndarray)
         fit_kw = {} if self.mesh is None else {"mesh": self.mesh}
-        params, losses = self.family.fit(
-            self.model,
-            blocks,
-            steps=cfg.ae_steps,
-            batch_size=cfg.batch_size,
-            lr=cfg.lr,
-            seed=cfg.seed,
-            log_every=200 if verbose else 0,
-            **fit_kw,
-        )
-        # honest sub-fp32 storage: round params through the container's
-        # storage dtype *before* any of them are used, so the latents,
-        # correction fit, and guarantee all see exactly the values the
-        # serialized decoder will replay (fp32 is the identity)
-        params = quantize_params(params, cfg.param_dtype_bytes)
-        latents = np.asarray(_batched(self._jit_encode, params, blocks))
+        with tracing.span("train.ae"):
+            params, losses = self.family.fit(
+                self.model,
+                blocks,
+                steps=cfg.ae_steps,
+                batch_size=cfg.batch_size,
+                lr=cfg.lr,
+                seed=cfg.seed,
+                log_every=200 if verbose else 0,
+                **fit_kw,
+            )
+        with tracing.span("fit.latents"):
+            # honest sub-fp32 storage: round params through the container's
+            # storage dtype *before* any of them are used, so the latents,
+            # correction fit, and guarantee all see exactly the values the
+            # serialized decoder will replay (fp32 is the identity)
+            params = quantize_params(params, cfg.param_dtype_bytes)
+            latents = np.asarray(_batched(self._jit_encode, params, blocks))
 
         corr_params = None
         if self.corr_net is not None:
-            # decode through the shared fused runtime (one dispatch, no
-            # chunked host round-trips); pointwise vecs are a transpose away
-            ae_vecs = self._decode_vecs(params, latents, None,
-                                        device=on_device)
-            nb, s = blocks.shape[:2]
-            if on_device:
-                vec_rec = ae_vecs.transpose(1, 2, 0).reshape(-1, s)
-                vec_orig = (
-                    blocks.reshape(nb, s, -1).transpose(0, 2, 1)
-                    .reshape(-1, s)
-                )  # blocks_to_pointwise, device-resident
-            else:
-                vec_rec = np.ascontiguousarray(
-                    ae_vecs.transpose(1, 2, 0).reshape(-1, self.n_species)
+            with tracing.span("train.correction"):
+                # decode through the shared fused runtime (one dispatch, no
+                # chunked host round-trips); pointwise vecs are a
+                # transpose away
+                ae_vecs = self._decode_vecs(params, latents, None,
+                                            device=on_device)
+                nb, s = blocks.shape[:2]
+                if on_device:
+                    vec_rec = ae_vecs.transpose(1, 2, 0).reshape(-1, s)
+                    vec_orig = (
+                        blocks.reshape(nb, s, -1).transpose(0, 2, 1)
+                        .reshape(-1, s)
+                    )  # blocks_to_pointwise, device-resident
+                else:
+                    vec_rec = np.ascontiguousarray(
+                        ae_vecs.transpose(1, 2, 0)
+                        .reshape(-1, self.n_species)
+                    )
+                    vec_orig = correction.blocks_to_pointwise(blocks)
+                corr_params, _ = correction.fit(
+                    self.corr_net, vec_rec, vec_orig,
+                    steps=cfg.corr_steps, seed=cfg.seed + 1,
+                    **fit_kw,
                 )
-                vec_orig = correction.blocks_to_pointwise(blocks)
-            corr_params, _ = correction.fit(
-                self.corr_net, vec_rec, vec_orig,
-                steps=cfg.corr_steps, seed=cfg.seed + 1,
-                **fit_kw,
-            )
-            corr_params = quantize_params(corr_params, cfg.param_dtype_bytes)
+                corr_params = quantize_params(corr_params,
+                                              cfg.param_dtype_bytes)
 
         self._ae_params = params
         self._corr_params = corr_params
@@ -394,7 +404,8 @@ class GBATCPipeline:
             # the out-of-core constraint is ingest/fit)
             self._vecs_orig = blocks.reshape(nb, s, -1).transpose(1, 0, 2)
         else:
-            self._vecs_orig = blocking.blocks_as_vectors(blocks)
+            with tracing.span("fit.blocks"):
+                self._vecs_orig = blocking.blocks_as_vectors(blocks)
         self._data = data
         self._shape = tuple(shape)
         self._data_nbytes = int(data_nbytes)
@@ -433,11 +444,12 @@ class GBATCPipeline:
         hit = self._prepared.get(key)
         if hit is not None:
             return hit
-        lat_q = quantize(self._latents, lat_bin)
         corr_params = None if skip_correction else self._corr_params
-        vecs_rec = self._decode_vecs(
-            self._ae_params, dequantize(lat_q, lat_bin), corr_params
-        )
+        with tracing.span("compress.latents"):
+            lat_q = quantize(self._latents, lat_bin)
+            vecs_rec = self._decode_vecs(
+                self._ae_params, dequantize(lat_q, lat_bin), corr_params
+            )
         prepared = self._gengine.prepare(
             self._vecs_orig, vecs_rec, reuse=self._last_prepared
         )
@@ -508,26 +520,29 @@ class GBATCPipeline:
             _latent_memo=latent_memo,
         )
 
-        rec_blocks = blocking.vectors_as_blocks(corrected, geom)
-        rec_normed = blocking.from_blocks(rec_blocks, shape, geom)
-        recon = rec_normed * rngs[:, None, None, None] + mn[:, None, None, None]
-
         bb = artifact.byte_breakdown()
-        if self._data is not None:
-            per_species = np.array(
-                [metrics.nrmse(self._data[s], recon[s])
-                 for s in range(self.n_species)]
-            )
-        else:
-            # streamed fit: the original field was never materialized.
-            # NRMSE is range-normalized and per-species min/max
-            # normalization makes the range exactly 1, so the normalized
-            # block-vector RMS *is* the NRMSE (up to float rounding; the
-            # guarantee itself is enforced in normalized units either way)
-            err = corrected - np.asarray(self._vecs_orig)
-            per_species = np.sqrt(np.mean(np.square(err), axis=(1, 2)))
+        with tracing.span("compress.report"):
+            rec_blocks = blocking.vectors_as_blocks(corrected, geom)
+            rec_normed = blocking.from_blocks(rec_blocks, shape, geom)
+            recon = (rec_normed * rngs[:, None, None, None]
+                     + mn[:, None, None, None])
+            if self._data is not None:
+                per_species = np.array(
+                    [metrics.nrmse(self._data[s], recon[s])
+                     for s in range(self.n_species)]
+                )
+            else:
+                # streamed fit: the original field was never
+                # materialized. NRMSE is range-normalized and per-species
+                # min/max normalization makes the range exactly 1, so the
+                # normalized block-vector RMS *is* the NRMSE (up to float
+                # rounding; the guarantee itself is enforced in
+                # normalized units either way)
+                err = corrected - np.asarray(self._vecs_orig)
+                per_species = np.sqrt(np.mean(np.square(err), axis=(1, 2)))
+            recon = recon.astype(np.float32)
         return CompressionReport(
-            recon=recon.astype(np.float32),
+            recon=recon,
             compression_ratio=self._data_nbytes / bb["total"],
             mean_nrmse=float(per_species.mean()),
             per_species_nrmse=per_species,
@@ -630,10 +645,11 @@ class GBATCCodec:
                 " (note: compress(target_nrmse=...) is keyword-only via the"
                 " data-first signature)"
             )
-        if self._pipe is None or self._pipe.n_species != data.shape[0]:
-            self._pipe = GBATCPipeline(self.cfg, n_species=data.shape[0],
-                                       mesh=self.mesh)
-        self._pipe.fit(data, verbose=verbose)
+        with tracing.span("fit"):
+            if self._pipe is None or self._pipe.n_species != data.shape[0]:
+                self._pipe = GBATCPipeline(self.cfg, n_species=data.shape[0],
+                                           mesh=self.mesh)
+            self._pipe.fit(data, verbose=verbose)
         return self
 
     def fit_stream(self, loader, verbose: bool = False, *,
@@ -655,13 +671,14 @@ class GBATCCodec:
         chunks). Shape/validation errors are never retried.
         """
         s = int(loader.shape[0])
-        if self._pipe is None or self._pipe.n_species != s:
-            self._pipe = GBATCPipeline(self.cfg, n_species=s,
-                                       mesh=self.mesh)
-        self._pipe.fit_stream(
-            loader, verbose=verbose, loader_retries=loader_retries,
-            retry_backoff=retry_backoff, _sleep=_sleep,
-        )
+        with tracing.span("fit"):
+            if self._pipe is None or self._pipe.n_species != s:
+                self._pipe = GBATCPipeline(self.cfg, n_species=s,
+                                           mesh=self.mesh)
+            self._pipe.fit_stream(
+                loader, verbose=verbose, loader_retries=loader_retries,
+                retry_backoff=retry_backoff, _sleep=_sleep,
+            )
         return self
 
     def compress(self, data: Optional[np.ndarray] = None,
@@ -679,8 +696,10 @@ class GBATCCodec:
             self.fit(data)
         if not self.fitted:
             raise RuntimeError("codec not fitted: pass data or call fit() first")
-        rep = self._pipe.compress(target_nrmse=target_nrmse, **kw)
-        return rep.artifact.to_bytes(), rep
+        # after any fit above, so that the two entry spans never nest
+        with tracing.span("compress"):
+            rep = self._pipe.compress(target_nrmse=target_nrmse, **kw)
+            return rep.artifact.to_bytes(), rep
 
     def write(self, path, data: Optional[np.ndarray] = None,
               target_nrmse: float = 1e-3, **kw) -> bytes:

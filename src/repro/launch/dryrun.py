@@ -13,7 +13,7 @@ For each cell:
     (multi-pod) meshes;
   * record memory_analysis(), cost_analysis(), and the collective-op bytes
     parsed from the post-SPMD optimized HLO into results/dryrun/<cell>.json
-    (consumed by benchmarks/roofline.py and EXPERIMENTS.md).
+    (consumed by EXPERIMENTS.md).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3_2_1b --shape train_4k

@@ -113,6 +113,28 @@ class TestGuarantee:
         corrected, arts = engine.select(prep, tau)
         _assert_held_and_replayed(engine, x, x_rec, corrected, arts, tau)
 
+    def test_fp32_hold_bound_counts_its_rounds(self, tmp_path):
+        """Traced, the hold loop's span says how many blocks its first
+        replay found over tau and how many rounds it took to hold them."""
+        import glob
+
+        import jax
+
+        x, x_rec = _fp32_case("gaussian")
+        engine, prep = _fp32_prepared(x, x_rec, rel_err=1e-3)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            engine.select(prep, 0.05)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+        (stats,) = [dict(e.stats)
+                    for plane in jax.profiler.ProfileData.from_file(
+                        path).planes
+                    for line in plane.lines for e in line.events
+                    if e.name == "gbatc.guarantee.hold_bound"]
+        assert stats["blocks_over"] > 0 and stats["rounds"] >= 2
+
     def test_decode_replay_matches(self):
         x, x_rec = _make_case(2)
         corrected, art = gae.guarantee(x, x_rec, 0.4)
