@@ -34,6 +34,7 @@ from repro.codec import format as wire
 from repro.codec.artifact import CompressedArtifact
 from repro.codec.params import pack_artifact_params
 from repro.core import container as container_format
+from repro.core import entropy
 from repro.core.container import ContainerWriter
 
 
@@ -88,13 +89,16 @@ def encode(artifact: CompressedArtifact,
     w.add("decoder", packed[0])
     if artifact.corr_params is not None:
         w.add("correction", packed[1])
-    with tracing.span("container.encode.guarantee"):
+    arts = artifact.species_guarantees
+    with tracing.span("container.encode.guarantee") as sp:
         if version >= container_format.FORMAT_VERSION_SELECTIVE:
-            w.add("guarantee",
-                  wire.pack_guarantee_stream(artifact.species_guarantees))
+            w.add("guarantee", wire.pack_guarantee_stream(arts))
         else:
-            for sidx, g in enumerate(artifact.species_guarantees):
+            for sidx, g in enumerate(arts):
                 w.add(f"guarantee{sidx}", g.to_bytes())
+        if sp.on:
+            sp.count(species=len(arts), dense_codebooks=sum(
+                entropy.dense_codebook(g.coeff_q) for g in arts))
     if version >= container_format.FORMAT_VERSION_INTEGRITY:
         # two-pass outer digest: the integrity payload's LENGTH is fixed
         # before its content (it depends only on stream count/names and

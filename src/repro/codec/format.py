@@ -241,21 +241,22 @@ def pack_guarantee_stream(arts) -> bytes:
     fixed 48-byte record, and every species' byte extents follow from the
     directory by prefix sums, so a reader can slice one species without
     parsing any sibling payload.
+
+    Species are entropy-coded independently, so their ``wire_parts``
+    run on the shared worker pool; the records and payloads are then
+    assembled in species order, the same bytes a serial pass writes.
     """
+    arts = list(arts)
+    wires = list(_pool().map(lambda g: g.wire_parts(), arts))
     parts = [_GDIR_HEAD.pack(len(arts))]
-    coeffs: list[bytes] = []
-    indexes: list[bytes] = []
-    bases: list[bytes] = []
-    for g in arts:
-        c, i, b = g.wire_parts()
+    for g, (c, i, b) in zip(arts, wires):
         parts.append(
             _GDIR_REC.pack(g.tau, g.coeff_bin, *g.basis.shape,
                            len(c), len(i), len(b))
         )
-        coeffs.append(c)
-        indexes.append(i)
-        bases.append(b)
-    return b"".join(parts + coeffs + indexes + bases)
+    for kind in range(3):
+        parts.extend(w[kind] for w in wires)
+    return b"".join(parts)
 
 
 class GuaranteeDirectory:
@@ -358,8 +359,8 @@ _POOL: Optional[ThreadPoolExecutor] = None
 
 
 def _pool() -> ThreadPoolExecutor:
-    """Shared workers for per-shard entropy packing (numpy releases the
-    GIL on the vectorized pack passes, so shards genuinely overlap)."""
+    """Shared workers for per-species and per-shard entropy packing (numpy
+    releases the GIL on the vectorized pack passes, so they overlap)."""
     global _POOL
     if _POOL is None:
         _POOL = ThreadPoolExecutor(max_workers=min(os.cpu_count() or 1, 8))

@@ -3,6 +3,8 @@
 The Huffman path is the paper's coder: quantized integer streams are
 frequency-counted, a canonical Huffman code is built, and the stream is
 bit-packed with a self-describing header (symbol table + code lengths).
+A narrow integer range is counted in linear time (``bincount``), anything
+else sorted (``np.unique``); both give the same codebook and bytes.
 Encoding is vectorized in numpy (loop over code-bit position, not symbols);
 decoding batches the k-bit table lookups over every bit position (a
 byte-parallel window pass — constant sweeps, not one per code bit) and
@@ -161,18 +163,75 @@ def _pack_payload(sym_codes, sym_lengths, offsets, total_bits) -> bytes:
     return out.astype(">u8").tobytes()[:nbytes]
 
 
+def _dense_span(values: np.ndarray) -> Optional[tuple[int, int]]:
+    """``(lo, span)`` when ``values`` can be counted in a dense table.
+
+    Integer values whose span ``hi - lo + 1`` is small next to their count
+    are counted by ``bincount`` in linear time; wide or sparse ranges and
+    non-integer dtypes return None. The span is computed with Python ints,
+    so int64 extremes cannot overflow.
+    """
+    if values.dtype.kind not in "iu" or values.size == 0:
+        return None
+    lo, hi = int(values.min()), int(values.max())
+    span = hi - lo + 1
+    return (lo, span) if span <= 4 * values.size + 1024 else None
+
+
+def dense_codebook(values: np.ndarray) -> bool:
+    """Whether the Huffman codebook of ``values`` is built by counting
+    (linear time) rather than by sorting."""
+    return _dense_span(np.asarray(values).ravel()) is not None
+
+
+def _codebook(values: np.ndarray):
+    """``(symbols, freqs, lengths, per_value)`` of a non-empty stream: the
+    codebook behind :func:`huffman_encode`, :func:`huffman_codebook` and
+    :func:`huffman_size_bytes`. ``symbols`` are the distinct values
+    ascending, in the values' dtype; ``per_value(table)`` maps a
+    per-symbol table onto the stream, one entry per value.
+
+    A narrow integer range is counted by ``bincount`` over ``values - lo``
+    and looked up in dense tables; the symbols are the nonzero counts'
+    positions plus ``lo`` — the same ascending symbols with the same
+    frequencies ``np.unique`` gives, so the code lengths (ties broken by
+    symbol index) and every byte are the same either way. Anything else
+    takes the ``np.unique`` sort.
+    """
+    dense = _dense_span(values)
+    if dense is None:
+        symbols, inverse = np.unique(values, return_inverse=True)
+        freqs = np.bincount(inverse)
+        return (symbols, freqs, _code_lengths(freqs),
+                lambda table: table[inverse])
+    lo, span = dense
+    # modular in the values' width: read unsigned, the differences are
+    # exact, since they lie in [0, span)
+    key = (values - values.dtype.type(lo)).view(
+        f"u{values.dtype.itemsize}").astype(np.intp, copy=False)
+    counts = np.bincount(key, minlength=span)
+    present = np.flatnonzero(counts)
+    symbols = present.astype(values.dtype) + values.dtype.type(lo)
+    freqs = counts[present]
+
+    def per_value(table):
+        full = np.zeros(span, table.dtype)
+        full[present] = table
+        return full[key]
+
+    return symbols, freqs, _code_lengths(freqs), per_value
+
+
 def huffman_encode(values: np.ndarray) -> bytes:
     """Encode an int array. Self-describing: header + packed bits."""
     values = np.asarray(values).ravel()
     if values.size == 0:
         return _MAGIC + struct.pack("<QI", 0, 0)
-    symbols, inverse = np.unique(values, return_inverse=True)
-    freqs = np.bincount(inverse)
-    lengths = _code_lengths(freqs)
+    symbols, _, lengths, per_value = _codebook(values)
     codes = _canonical_codes(lengths)
 
-    sym_lengths = lengths[inverse]
-    sym_codes = codes[inverse]
+    sym_lengths = per_value(lengths)
+    sym_codes = per_value(codes)
     offsets = np.concatenate(([0], np.cumsum(sym_lengths)[:-1]))
     total_bits = int(sym_lengths.sum())
     payload = _pack_payload(sym_codes, sym_lengths, offsets, total_bits)
@@ -199,9 +258,8 @@ def huffman_codebook(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     values = np.asarray(values).ravel()
     if values.size == 0:
         return np.zeros(0, np.int64), np.zeros(0, np.int64)
-    symbols, inverse = np.unique(values, return_inverse=True)
-    freqs = np.bincount(inverse)
-    return symbols.astype(np.int64), _code_lengths(freqs)
+    symbols, _, lengths, _ = _codebook(values)
+    return symbols.astype(np.int64), lengths
 
 
 def huffman_codebook_parts(parts) -> tuple[np.ndarray, np.ndarray]:
@@ -887,9 +945,7 @@ def huffman_size_bytes(values: np.ndarray) -> int:
     values = np.asarray(values).ravel()
     if values.size == 0:
         return 4 + 12
-    symbols, inverse = np.unique(values, return_inverse=True)
-    freqs = np.bincount(inverse)
-    lengths = _code_lengths(freqs)
+    symbols, freqs, lengths, _ = _codebook(values)
     total_bits = int((freqs * lengths).sum())
     header = 4 + 12 + 9 * len(symbols)
     return header + (total_bits + 7) // 8

@@ -604,3 +604,54 @@ class TestFp16ParamStorage:
              for s in range(small_data.shape[0])]
         )
         assert per.max() <= target * (1 + 1e-3)
+
+
+def _serial_guarantee_stream(arts) -> bytes:
+    """The combined guarantee stream assembled one species at a time from
+    ``wire_parts``: count, directory records, then the coeff, index and
+    basis payloads, each group in species order."""
+    wires = [g.wire_parts() for g in arts]
+    records = [
+        codec._GDIR_REC.pack(g.tau, g.coeff_bin, *g.basis.shape,
+                             *(len(p) for p in w))
+        for g, w in zip(arts, wires)
+    ]
+    payloads = [w[kind] for kind in range(3) for w in wires]
+    return codec._GDIR_HEAD.pack(len(arts)) + b"".join(records + payloads)
+
+
+class TestGuaranteeStreamPacking:
+    @staticmethod
+    def _artifacts(n_species: int) -> list:
+        """``n_species`` artifacts over 90 blocks of 48 values; every third
+        one empty, the rest from the guarantee at varied tolerances."""
+        rng = np.random.default_rng(n_species)
+        arts = []
+        for sidx in range(n_species):
+            if sidx % 3 == 1:
+                arts.append(gae.GuaranteeArtifact.empty(nb=90, d=48, tau=0.4))
+                continue
+            x = rng.normal(size=(90, 48)).astype(np.float32)
+            x_rec = x + 0.2 * rng.normal(size=x.shape).astype(np.float32)
+            arts.append(gae.guarantee(x, x_rec, 0.2 + 0.05 * sidx)[1])
+        return arts
+
+    @pytest.mark.parametrize("n_species", [1, 3, 13])
+    def test_pooled_pack_matches_serial_assembly(self, n_species):
+        arts = self._artifacts(n_species)
+        assert any(g.coeff_q.size == 0 for g in arts) == (n_species > 1)
+        assert codec.pack_guarantee_stream(arts) == \
+            _serial_guarantee_stream(arts)
+
+    def test_encode_matches_serial_guarantee_stream(
+        self, blob_and_report, monkeypatch
+    ):
+        """A whole container with the pooled guarantee stream is the blob
+        whose guarantee stream was assembled serially."""
+        from repro.codec import format as wire
+
+        blob, rep = blob_and_report
+        pooled = codec.encode(rep.artifact)
+        monkeypatch.setattr(wire, "pack_guarantee_stream",
+                            _serial_guarantee_stream)
+        assert codec.encode(rep.artifact) == pooled == blob

@@ -119,6 +119,65 @@ class TestHuffman:
         with pytest.raises(ValueError):
             entropy.huffman_decode(blob[: len(blob) // 2])
 
+    _I64 = np.iinfo(np.int64)
+    _RNG = np.random.default_rng(11)
+    # (values, takes the linear-time codebook)
+    CODEBOOK_CASES = {
+        "narrow_laplacian": (np.rint(_RNG.laplace(
+            scale=20.0, size=60000)).astype(np.int64), True),
+        "all_negative": (-1 - _RNG.integers(0, 300, size=4000), True),
+        "single_symbol": (np.full(513, -9, np.int64), True),
+        "empty": (np.zeros(0, np.int64), False),
+        "int32": (np.rint(_RNG.laplace(scale=4.0, size=2500)).astype(
+            np.int32), True),
+        "sparse_wide_range": (_RNG.integers(-10**15, 10**15, size=700),
+                              False),
+        "int64_min_edge": (_I64.min + _RNG.integers(0, 12, size=900), True),
+        "int64_max_edge": (_I64.max - _RNG.integers(0, 12, size=900), True),
+        "int64_both_extremes": (np.array(
+            [_I64.min, _I64.max, 0, 0, -1, _I64.min], np.int64), False),
+    }
+
+    @staticmethod
+    def _unique_reference(vals):
+        """The sort-based construction: ``np.unique`` symbols, frequencies
+        through the inverse, per-value lookups through the inverse."""
+        vals = np.asarray(vals).ravel()
+        if vals.size == 0:
+            return (b"HUF1" + bytes(12), (np.zeros(0, np.int64),) * 2, 16)
+        symbols, inverse = np.unique(vals, return_inverse=True)
+        freqs = np.bincount(inverse)
+        lengths = entropy._code_lengths(freqs)
+        codes = entropy._canonical_codes(lengths)
+        sym_lengths, sym_codes = lengths[inverse], codes[inverse]
+        offsets = np.concatenate(([0], np.cumsum(sym_lengths)[:-1]))
+        total_bits = int(sym_lengths.sum())
+        blob = (
+            b"HUF1" + np.array([vals.size], "<u8").tobytes()
+            + np.array([len(symbols)], "<u4").tobytes()
+            + symbols.astype("<i8").tobytes() + lengths.astype("<u1").tobytes()
+            + entropy._pack_payload(sym_codes, sym_lengths, offsets,
+                                    total_bits)
+        )
+        size = 16 + 9 * len(symbols) + (total_bits + 7) // 8
+        return blob, (symbols.astype(np.int64), lengths), size
+
+    @pytest.mark.parametrize("case", sorted(CODEBOOK_CASES))
+    def test_codebook_matches_unique_construction(self, case):
+        """Encode, codebook and size are bitwise the sort-based
+        construction's whether the codebook is counted (narrow integer
+        ranges) or sorted (the fallback), and the stream round-trips."""
+        vals, dense = self.CODEBOOK_CASES[case]
+        assert entropy.dense_codebook(vals) is dense
+        blob, (symbols, lengths), size = self._unique_reference(vals)
+        assert entropy.huffman_encode(vals) == blob
+        got_symbols, got_lengths = entropy.huffman_codebook(vals)
+        assert got_symbols.dtype == symbols.dtype
+        assert np.array_equal(got_symbols, symbols)
+        assert np.array_equal(got_lengths, lengths)
+        assert entropy.huffman_size_bytes(vals) == size == len(blob)
+        assert np.array_equal(entropy.huffman_decode(blob), vals)
+
     @pytest.mark.parametrize("seed", range(5))
     def test_packed_encoder_parity_with_bitloop(self, seed):
         """The table-driven batched pack must be bit-identical to the

@@ -94,6 +94,15 @@ def test_entry_spans_carry_the_jit_cost(jobs):
     assert compiled - {"gbatc.fit", "gbatc.compress"}
 
 
+def test_guarantee_packing_counts_its_codebooks(jobs):
+    """The guarantee stream's span counts the species it packed and the
+    coefficient streams whose codebook was counted in linear time."""
+    (stats,) = [st for n, _, _, st in jobs[2]
+                if n == "gbatc.container.encode.guarantee"]
+    assert stats["species"] == 4
+    assert 1 <= stats["dense_codebooks"] <= 4
+
+
 def test_span_records_nothing_with_the_profiler_off():
     assert not jax.profiler.TraceAnnotation.is_enabled()
     before = tracing._totals.snapshot()
