@@ -38,7 +38,6 @@ from repro.core import entropy
 from repro.core.container import ContainerWriter
 
 
-@tracing.span("container.encode")
 def encode(artifact: CompressedArtifact,
            version: int = container_format.FORMAT_VERSION_FAMILY,
            *, shard_tgroups: Optional[int] = None) -> bytes:
@@ -55,7 +54,18 @@ def encode(artifact: CompressedArtifact,
     default of ``format.DEFAULT_SHARD_TGROUPS`` gives the finest
     window a block-row decode can address. Oversized values clamp to
     one shard.
+
+    The stage's span counts the container's ``bytes`` and, of them, the
+    ``decoder_bytes`` of the decoder's parameter stream.
     """
+    with tracing.span("container.encode") as sp:
+        blob = _encode(artifact, version, shard_tgroups, sp)
+        sp.count(bytes=len(blob))
+    return blob
+
+
+def _encode(artifact: CompressedArtifact, version: int,
+            shard_tgroups: Optional[int], sp: tracing.Span) -> bytes:
     cfg = families.structural(artifact.cfg)
     if version not in container_format.SUPPORTED_VERSIONS:
         raise ValueError(f"unknown container version {version}")
@@ -87,6 +97,7 @@ def encode(artifact: CompressedArtifact,
             artifact.ae_params, artifact.corr_params, cfg.param_dtype_bytes
         )
     w.add("decoder", packed[0])
+    sp.count(decoder_bytes=len(packed[0]))
     if artifact.corr_params is not None:
         w.add("correction", packed[1])
     arts = artifact.species_guarantees

@@ -345,7 +345,8 @@ class GBATCPipeline:
         cfg = self.cfg
         on_device = not isinstance(blocks, np.ndarray)
         fit_kw = {} if self.mesh is None else {"mesh": self.mesh}
-        with tracing.span("train.ae"):
+        with tracing.span("train.ae") as sp:
+            sp.count(steps=cfg.ae_steps, batch=cfg.batch_size)
             params, losses = self.family.fit(
                 self.model,
                 blocks,
@@ -362,7 +363,9 @@ class GBATCPipeline:
             # correction fit, and guarantee all see exactly the values the
             # serialized decoder will replay (fp32 is the identity)
             params = quantize_params(params, cfg.param_dtype_bytes)
-            latents = np.asarray(_batched(self._jit_encode, params, blocks))
+            with tracing.span("fit.latents.encode"):
+                latents = np.asarray(_batched(self._jit_encode, params,
+                                              blocks))
 
         corr_params = None
         if self.corr_net is not None:
@@ -447,9 +450,11 @@ class GBATCPipeline:
         corr_params = None if skip_correction else self._corr_params
         with tracing.span("compress.latents"):
             lat_q = quantize(self._latents, lat_bin)
-            vecs_rec = self._decode_vecs(
-                self._ae_params, dequantize(lat_q, lat_bin), corr_params
-            )
+            # ends with the host fetch, so with the device's work
+            with tracing.span("compress.latents.decode"):
+                vecs_rec = self._decode_vecs(
+                    self._ae_params, dequantize(lat_q, lat_bin), corr_params
+                )
         prepared = self._gengine.prepare(
             self._vecs_orig, vecs_rec, reuse=self._last_prepared
         )

@@ -2,16 +2,16 @@
 
 The paper group's follow-up (arxiv 2409.05357) replaces the conv block
 autoencoder with attention for better rate at the same bound; this module
-is that encoder/decoder pair over the *same* block instances the conv AE
-consumes: an (NB, S, bt, ph, pw) block flattens to ``S * bt`` patch
-tokens of dimension ``ph * pw`` (one token per species per frame of the
-block), a dense projection + sinusoidal positions lifts them to
-``d_model``, ``depth`` pre-norm non-causal transformer blocks (multi-head
-attention + SwiGLU FFN, the :mod:`repro.models.transformer` idioms) mix
-them, and one FC maps the flattened token grid to the shared 36-dim
-latent. The decoder mirrors exactly (its own projection, blocks, and
-un-embed), so the codec stores decoder-side parameters only, like the
-conv family.
+is the repository's own encoder/decoder pair after that idea, not the
+paper's architecture, over the *same* block instances the conv AE uses:
+an (NB, S, bt, ph, pw) block flattens to ``S * bt`` patch tokens of
+dimension ``ph * pw`` (one token per species per frame of the block), a
+dense projection + sinusoidal positions lifts them to ``d_model``,
+``depth`` pre-norm non-causal transformer blocks (multi-head attention +
+SwiGLU FFN, the :mod:`repro.models.transformer` idioms) mix them, and one
+FC maps the flattened token grid to the shared 36-dim latent. The decoder
+mirrors exactly (its own projection, blocks, and un-embed), so the codec
+stores decoder-side parameters only, like the conv family.
 
 Everything downstream is family-agnostic: ``fit`` trains through the same
 compiled :class:`repro.train.train_loop.MiniBatchTrainer`, the unchanged

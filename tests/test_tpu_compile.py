@@ -135,6 +135,19 @@ def test_conv_fused_decode_compiles(shape, pipe):
     _check(compiled, kernel=False)
 
 
+def test_attention_fused_decode_compiles(shape):
+    """The attention family's fused decode at the ``s3d_attention``
+    benchmark configuration's arch words, on one chunk of the grid."""
+    cfg = PipelineConfig(family="attention", arch=(128, 2, 4, 512),
+                         use_correction=False)
+    rt = codec_runtime._runtime(cfg, S, False)
+    params = jax.eval_shape(rt.model.init, jax.random.PRNGKey(0))
+    lat = shape((codec_runtime._FUSED_CHUNK, cfg.latent), jnp.float32)
+    compiled = rt.jit_fused.lower(_tree_shapes(shape, params), None,
+                                  lat).compile()
+    _check(compiled, kernel=False)
+
+
 def test_scan_trainer_step_compiles(shape, pipe):
     cfg = pipe.cfg
     trainer = train_loop.MiniBatchTrainer(

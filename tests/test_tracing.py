@@ -15,16 +15,19 @@ import numpy as np
 import pytest
 
 from repro import tracing
+from repro.core.container import ContainerReader
 from repro.core.pipeline import GBATCCodec, PipelineConfig
 from repro.data import s3d
 
 STAGES = {
     "gbatc.fit": {
         "gbatc.fit.blocks", "gbatc.train.ae", "gbatc.fit.latents",
-        "gbatc.train.correction", "gbatc.decode.fused",
+        "gbatc.fit.latents.encode", "gbatc.train.correction",
+        "gbatc.decode.fused",
     },
     "gbatc.compress": {
-        "gbatc.compress.latents", "gbatc.decode.fused",
+        "gbatc.compress.latents", "gbatc.compress.latents.decode",
+        "gbatc.decode.fused",
         "gbatc.guarantee.prepare", "gbatc.guarantee.prepare.residual",
         "gbatc.guarantee.prepare.pca", "gbatc.guarantee.prepare.project",
         "gbatc.guarantee.prepare.order", "gbatc.guarantee.prepare.stage",
@@ -48,9 +51,12 @@ def _spans(trace_dir) -> list[tuple[str, float, float, dict]]:
     return out
 
 
+CFG = PipelineConfig(ae_steps=20, corr_steps=10, conv_channels=(4, 8),
+                     latent=6, batch_size=16)
+
+
 def _job(data) -> bytes:
-    cfg = PipelineConfig(ae_steps=20, corr_steps=10, conv_channels=(4, 8),
-                         latent=6, batch_size=16)
+    cfg = CFG
     blob, _ = GBATCCodec(cfg).fit(data).compress_report(target_nrmse=1e-3)
     return blob
 
@@ -101,6 +107,18 @@ def test_guarantee_packing_counts_its_codebooks(jobs):
                 if n == "gbatc.container.encode.guarantee"]
     assert stats["species"] == 4
     assert 1 <= stats["dense_codebooks"] <= 4
+
+
+def test_trainer_and_container_spans_carry_their_sizes(jobs):
+    """The AE trainer's span counts its steps and batch; the container's
+    span its bytes and, of them, the decoder stream's."""
+    untraced, _, spans = jobs
+    (train,) = [st for n, _, _, st in spans if n == "gbatc.train.ae"]
+    assert (train["steps"], train["batch"]) == (CFG.ae_steps,
+                                                 CFG.batch_size)
+    (cont,) = [st for n, _, _, st in spans if n == "gbatc.container.encode"]
+    assert cont["bytes"] == len(untraced)
+    assert cont["decoder_bytes"] == len(ContainerReader(untraced)["decoder"])
 
 
 def test_span_records_nothing_with_the_profiler_off():
