@@ -35,8 +35,12 @@ bound:
 * ``select(prepared, tau, coeff_bin)`` — the cheap per-error-bound pass:
   one jitted dispatch fuses quantization, the gain cumsum/cut (jnp ops
   under ``jax.enable_x64``), and the masked select-and-accumulate correction
-  GEMM (``gbatc_select_accumulate``); the host then assembles the CSR
-  artifact with vectorized ``nonzero``/``cumsum`` passes.
+  GEMM (``gbatc_select_accumulate``) and returns only the (S, NB) cut; the
+  host forms the coefficient integers from its own copy of the projections
+  and assembles the CSR artifact with vectorized ``nonzero``/``cumsum``
+  passes. The fp64 residual and the integers are formed per species on a
+  shared host pool; on the MXU path no float64 (S, NB, D) tensor crosses
+  to or from the device.
 
 ``pipeline.compress`` sweeps error bounds against one fitted model, so the
 prepare cost amortizes across the sweep — that, plus the loop-free artifact
@@ -323,6 +327,48 @@ def _stable_desc_order(energy: np.ndarray) -> np.ndarray:
     return order.astype(np.int32)
 
 
+def _residual_stack(
+    x: np.ndarray, x_rec: np.ndarray, species: np.ndarray
+) -> tuple[np.ndarray, np.ndarray]:
+    """The fp64 residual ``x - x_rec`` of the given species and its
+    per-block energies, (len(species), NB, D) and (len(species), NB).
+
+    Each species is formed straight into its slice of one float64 array,
+    on the shared pool: the subtraction is elementwise and the energy a
+    reduction along each row, so both are bitwise the whole-stack
+    ``x.astype(f64) - x_rec.astype(f64)`` and ``sum(r**2, axis=2)``.
+    """
+    _, nb, d = x.shape
+    residual = np.empty((len(species), nb, d), np.float64)
+    norms2 = np.empty((len(species), nb), np.float64)
+
+    def work(i):
+        sidx = species[i]
+        np.subtract(x[sidx], x_rec[sidx], out=residual[i], dtype=np.float64)
+        norms2[i] = np.sum(residual[i] ** 2, axis=1)
+
+    list(_pool().map(work, range(len(species))))
+    return residual, norms2
+
+
+def _coefficient_integers(coeffs: np.ndarray, bin_size: float) -> np.ndarray:
+    """The quantized coefficients ``rint(coeffs / bin_size)``, exact
+    integers held in float64, formed per species on the shared pool.
+
+    The quotient is taken in float64 whatever ``coeffs``' dtype, as the
+    select program divides: an fp32 projection widens exactly, and IEEE
+    division and rounding give the same integers on any host.
+    """
+    cq = np.empty(coeffs.shape, np.float64)
+
+    def work(sidx):
+        np.divide(coeffs[sidx], bin_size, out=cq[sidx], dtype=np.float64)
+        np.rint(cq[sidx], out=cq[sidx])
+
+    list(_pool().map(work, range(coeffs.shape[0])))
+    return cq
+
+
 @dataclasses.dataclass
 class PreparedGuarantee:
     """Tau-independent guarantee state (see GuaranteeEngine.prepare)."""
@@ -481,12 +527,15 @@ class GuaranteeEngine:
             cut = cut_fp32 if coeffs_sorted.dtype == jnp.float32 else cut_fp64
             m, achieved = cut(coeffs_sorted, norms2, tau2, bin_size)
             m_eff = jnp.where(norms2 > tau2, m, 0).astype(jnp.int32)
-            cq = jnp.rint(coeffs / bin_size)  # index-ordered ints (as f64)
+            # the integers themselves are formed on the host from the
+            # host's copy of coeffs (_coefficient_integers): only the
+            # (S, NB) cut comes back
+            cqv = jnp.rint(coeffs / bin_size) * bin_size
             corrected = gbatc_select_accumulate(
-                x_rec, (cq * bin_size).astype(jnp.float32), inv_rank, m_eff,
-                basis32, **opts
+                x_rec, cqv.astype(jnp.float32), inv_rank, m_eff, basis32,
+                **opts
             )
-            return corrected, cq, m_eff, achieved
+            return corrected, m_eff, achieved
 
         self._project_jit = jax.jit(project_fn)
         self._select_jit = jax.jit(select_fn)
@@ -570,13 +619,9 @@ class GuaranteeEngine:
         # residual in the caller's precision (matches the oracle's
         # float64 contract even for float64 reconstructions); only the
         # correction kernel input and fast-path output are float32
-        full = len(stale) == s
         with tracing.span("guarantee.prepare.residual"):
-            x_rec_arr = np.asarray(x_rec)
-            residual = (x if full else x[stale]).astype(np.float64)
-            residual -= (x_rec_arr if full
-                         else x_rec_arr[stale]).astype(np.float64)
-            norms2_stale = np.sum(residual**2, axis=2)
+            residual, norms2_stale = _residual_stack(x, np.asarray(x_rec),
+                                                     stale)
         # PCA on host numpy: the D x D eigh is tiny, and sharing the exact
         # gram/eigh path with the numpy oracle is what makes the engine's
         # byte accounting bit-identical to it.
@@ -674,14 +719,16 @@ class GuaranteeEngine:
             return prep.x_rec32.astype(np.float32), arts
 
         bin_size = _effective_bin(coeff_bin, tau, d)
-        with tracing.span("guarantee.select"):
+        with tracing.span("guarantee.select") as sp:
+            cq = _coefficient_integers(prep.coeffs, bin_size)
             if self.select_backend == "host":
-                corrected, cq, m_eff, achieved = self._select_host(
-                    prep, needs, tau2, bin_size
+                corrected, m_eff, achieved = self._select_host(
+                    prep, cq, needs, tau2, bin_size
                 )
+                fetched = [corrected]
             else:
                 with jax.enable_x64(True):
-                    corrected, cq, m_eff, achieved = self._dispatch(
+                    corrected, m_eff, achieved = self._dispatch(
                         "select",
                         prep.coeffs_dev,
                         prep.coeffs_sorted_dev,
@@ -692,9 +739,14 @@ class GuaranteeEngine:
                         np.float64(tau2),
                         np.float64(bin_size),
                     )
-                    cq = np.asarray(cq)
                     m_eff = np.asarray(m_eff)
                     achieved = np.asarray(achieved)
+                # the default engine leaves the program's correction on the
+                # device; a sharded engine's dispatch fetches it
+                fetched = [m_eff, achieved] + (
+                    [corrected] if isinstance(corrected, np.ndarray) else [])
+            if sp.on:
+                sp.count(fetch_bytes=sum(a.nbytes for a in fetched))
 
             # Guaranteed by bin clamp, but assert rather than assume:
             target = prep.norms2 - tau2
@@ -753,18 +805,18 @@ class GuaranteeEngine:
                     )
                 m_eff = (m_eff + over).astype(np.int32)
 
-    def _select_host(self, prep, needs, tau2, bin_size):
+    def _select_host(self, prep, cq, needs, tau2, bin_size):
         """Host-numpy selection math + Pallas masked-correction dispatch.
 
         Arithmetic mirrors the oracle expression for expression, so the
         cumulative gains — and therefore the cut — are bit-identical to it,
         not merely scan-order-close. Species are processed by the shared
-        thread pool (disjoint slices, pure per-slice ops).
+        thread pool (disjoint slices, pure per-slice ops). ``cq`` holds the
+        coefficient integers (:func:`_coefficient_integers`).
         """
         s, nb, d = prep.shape
         m_eff = np.empty((s, nb), np.int32)
         achieved = np.empty((s, nb), np.float64)
-        cq = np.empty((s, nb, d), np.float64)
         cqv32 = np.empty((s, nb, d), np.float32)
         # row-chunked tasks: every op is row-independent, and ~1k-row
         # slices keep the ~10-pass working set L2-resident
@@ -794,8 +846,6 @@ class GuaranteeEngine:
                 cum, (m - 1)[:, None], axis=-1
             )[:, 0]
             m_eff[sidx, rows] = np.where(needs[sidx, rows], m, 0)
-            np.divide(prep.coeffs[sidx, rows], bin_size, out=cq[sidx, rows])
-            np.rint(cq[sidx, rows], out=cq[sidx, rows])
             # (int * bin) in f64, then cast on store — must match the
             # decode path's dequantize(...).astype(f32) bit for bit
             np.multiply(cq[sidx, rows], bin_size, out=cum)
@@ -810,7 +860,7 @@ class GuaranteeEngine:
                 prep.basis32_dev,
             )
         )
-        return corrected, cq, m_eff, achieved
+        return corrected, m_eff, achieved
 
     @staticmethod
     @tracing.span("guarantee.artifacts")

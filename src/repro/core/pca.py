@@ -41,7 +41,7 @@ def pca_basis_stack(
     independent, so results do not depend on scheduling).
     """
     s, _, d = residuals.shape
-    r = residuals.astype(np.float64)
+    r = np.asarray(residuals, dtype=np.float64)  # no copy of an fp64 stack
     grams = np.matmul(r.transpose(0, 2, 1), r)
     bases = np.empty((s, d, d), np.float64)
     eigvals = np.empty((s, d), np.float64)

@@ -247,7 +247,7 @@ _KERNEL_PLANS = {
         args=((0, 1), (0, None)), outs=((0, 1),), x64=True),
     "select": dict(
         args=((0, 1), (0, 1), (0, 1), (0, 1), (0, 1), (0, None), None, None),
-        outs=((0, 1), (0, 1), (0, 1), (0, 1)), x64=True),
+        outs=((0, 1), (0, 1), (0, 1)), x64=True),
     "correct": dict(
         args=((0, 1), (0, 1), (0, 1), (0, 1), (0, None)),
         outs=((0, 1),), x64=False),

@@ -135,6 +135,45 @@ class TestGuarantee:
                     if e.name == "gbatc.guarantee.hold_bound"]
         assert stats["blocks_over"] > 0 and stats["rounds"] >= 2
 
+    @pytest.mark.parametrize("case, rel_err", [
+        ("gaussian", 0.0), ("heavy_tailed", 0.0), ("gaussian", 1e-3)])
+    def test_fp32_artifacts_match_the_device_formed_integers(self, case,
+                                                             rel_err):
+        """The jit backend forms the coefficient integers on the host from
+        its fp32 projections; its artifacts and correction must be those
+        built from the integers an x64 program forms on the device,
+        ``rint(coeffs / bin)``, through the same cut and hold."""
+        import jax
+        import jax.numpy as jnp
+
+        tau = 0.05
+        x, x_rec = _fp32_case(case)
+        engine, prep = _fp32_prepared(x, x_rec, rel_err=rel_err)
+        bin_size = gae._effective_bin(0.0, tau, x.shape[2])
+        tau2 = tau * tau
+        needs = prep.norms2 > tau2
+        with jax.enable_x64(True):
+            cq_dev = np.asarray(jax.jit(lambda c, b: jnp.rint(c / b))(
+                prep.coeffs_dev, np.float64(bin_size)))
+            _, m_eff, _ = engine._select_jit(
+                prep.coeffs_dev, prep.coeffs_sorted_dev, prep.inv_rank_dev,
+                prep.norms2_dev, prep.x_rec_dev, prep.basis32_dev,
+                np.float64(tau2), np.float64(bin_size),
+            )
+        assert cq_dev.dtype == np.float64
+        np.testing.assert_array_equal(
+            gae._coefficient_integers(prep.coeffs, bin_size), cq_dev)
+        want, m_held = engine._hold_bound(
+            prep, cq_dev, np.asarray(m_eff), needs, tau2, bin_size)
+        want_arts = engine._build_artifacts(prep, m_held, cq_dev, needs,
+                                            bin_size, tau)
+        corrected, arts = engine.select(prep, tau)
+        np.testing.assert_array_equal(corrected, want)
+        for a, b in zip(arts, want_arts):
+            _assert_artifact_equal(a, b)
+        assert [a.to_bytes() for a in arts] == [
+            b.to_bytes() for b in want_arts]
+
     def test_decode_replay_matches(self):
         x, x_rec = _make_case(2)
         corrected, art = gae.guarantee(x, x_rec, 0.4)
@@ -312,6 +351,49 @@ class TestEngineOracleParity:
             np.testing.assert_array_equal(corr_sweep, corr_fresh)
             for a, b in zip(arts_sweep, arts_fresh):
                 _assert_artifact_equal(a, b)
+
+
+class TestPooledPrepare:
+    """``prepare`` forms the fp64 residual per species on the host pool;
+    every tau-independent quantity must be bitwise the serial whole-stack
+    construction, on a cold prepare and on a reuse that recomputes only
+    the changed species."""
+
+    @pytest.mark.parametrize("rec_dtype", [np.float32, np.float64])
+    @pytest.mark.parametrize("path", ["full", "reuse"])
+    def test_bitwise_the_serial_fp64_construction(self, path, rec_dtype):
+        import jax
+
+        from repro.core import pca
+
+        rng = np.random.default_rng(21)
+        x = rng.normal(size=(4, 300, 80)).astype(np.float32)
+        x_rec = (x + 0.1 * rng.normal(size=x.shape)).astype(rec_dtype)
+        engine = gae.GuaranteeEngine(interpret=True)
+        reuse = None
+        if path == "reuse":
+            reuse = engine.prepare(x, x_rec)
+            x_rec = x_rec.copy()
+            x_rec[[1, 3]] += np.asarray(
+                0.05 * rng.normal(size=(2, 300, 80)), rec_dtype)
+        prep = engine.prepare(x, x_rec, reuse=reuse)
+
+        r = x.astype(np.float64)
+        r -= x_rec.astype(np.float64)
+        norms2 = np.sum(r**2, axis=2)
+        bases = np.stack([pca.pca_basis(r[s])[0] for s in range(len(r))])
+        with jax.enable_x64(True):
+            coeffs = np.array(engine._project_jit(r, bases))
+
+        pooled, pooled_norms2 = gae._residual_stack(x, x_rec,
+                                                    np.arange(len(x)))
+        np.testing.assert_array_equal(pooled, r)
+        np.testing.assert_array_equal(pooled_norms2, norms2)
+        np.testing.assert_array_equal(prep.norms2, norms2)
+        np.testing.assert_array_equal(prep.basis, bases)
+        np.testing.assert_array_equal(prep.coeffs, coeffs)
+        np.testing.assert_array_equal(
+            pca.pca_basis_stack(r)[0], pca.pca_basis_stack(r.copy())[0])
 
 
 class TestCSRArtifact:

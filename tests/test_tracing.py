@@ -109,6 +109,53 @@ def test_guarantee_packing_counts_its_codebooks(jobs):
     assert 1 <= stats["dense_codebooks"] <= 4
 
 
+@pytest.mark.parametrize("profiler", ["on", "off"])
+@pytest.mark.parametrize("backend", ["host", "jit"])
+def test_guarantee_select_counts_what_it_fetched(backend, profiler,
+                                                 monkeypatch, tmp_path):
+    """While the profiler records, the select stage counts the bytes it
+    brought back from the device: the host cut's fp32 correction, or the
+    select program's (S, NB) cut and no (S, NB, D) tensor. With the
+    profiler off the stage counts nothing."""
+    import contextlib
+
+    from repro.core import gae
+
+    handles = []
+    real = tracing.span
+
+    @contextlib.contextmanager
+    def spy(name, **meta):
+        with real(name, **meta) as handle:
+            if name == "guarantee.select":
+                handles.append(handle)
+            yield handle
+
+    monkeypatch.setattr(tracing, "span", spy)
+    rng = np.random.default_rng(5)
+    s, nb, d = 2, 96, 80
+    x = rng.normal(size=(s, nb, d)).astype(np.float32)
+    x_rec = x + 0.1 * rng.normal(size=x.shape).astype(np.float32)
+    engine = gae.GuaranteeEngine(interpret=True, select_backend=backend)
+    prep = engine.prepare(x, x_rec)
+    if profiler == "on":
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.select(prep, 0.5)
+    finally:
+        if profiler == "on":
+            jax.profiler.stop_trace()
+    (handle,) = handles
+    if profiler == "off":
+        assert not handle.on and "fetch_bytes" not in handle.stats
+        return
+    want = s * nb * d * 4 if backend == "host" else s * nb * (4 + 8)
+    assert handle.stats["fetch_bytes"] == want
+    (stats,) = [st for n, _, _, st in _spans(tmp_path)
+                if n == "gbatc.guarantee.select"]
+    assert stats["fetch_bytes"] == want
+
+
 def test_trainer_and_container_spans_carry_their_sizes(jobs):
     """The AE trainer's span counts its steps and batch; the container's
     span its bytes and, of them, the decoder stream's."""
