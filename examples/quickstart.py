@@ -24,17 +24,12 @@ outgrow one device, the whole fit/compress path shards over a
 ``("data",)`` mesh — DP trainer, species-sharded guarantee engine,
 streamed sharded ingest — with byte-identical containers (step 9 below).
 
-Performance expectations (2-core CI-class CPU; see BENCH_throughput.json
-for the currently measured numbers): the 500-step fit below runs on the
-compiled mini-batch engine (device-resident data, no per-step host sync)
-at roughly 20+ steps/s — most of a fit's wall clock is now SGD compute,
-and *refitting* the same codec is warm-start fast because the compiled
-training program is cached. Standalone ``decompress`` runs the fused
-device-resident decode (one dispatch for decoder+correction, batched
-guarantee replay). Benchmark both ends against the retained pre-change
-paths with:
-
-  PYTHONPATH=src python -m benchmarks.bench_throughput
+The 500-step fit below runs on the compiled mini-batch engine
+(device-resident data, no per-step host sync), and *refitting* the same
+codec is warm-start fast because the compiled training program is cached.
+Standalone ``decompress`` runs the fused device-resident decode (one
+dispatch for decoder+correction, batched guarantee replay). Speed is
+measured on the chip only, by the cells under ``bench/``.
 
 The invariants this pipeline rests on (decode reads only the blob, wire
 errors carry stream/unit coordinates, hot programs never retrace, the
@@ -61,7 +56,7 @@ from repro.data import s3d
 def main():
     # 1. a small S3D-like dataset: 12 species, 16 frames, 80x80 grid
     #    (fixed overheads — decoder, PCA bases — amortize with data volume;
-    #    benchmarks/bench_compression.py runs the paper-scale version)
+    #    the bench/ cells run the paper's width on the chip)
     ds = s3d.generate(s3d.S3DConfig(n_species=12, n_time=16, height=80,
                                     width=80, seed=0))
     data = ds["species"]
@@ -116,8 +111,7 @@ def main():
     touched = pd.bytes_parsed(species=[5])
     print(f"\nselective decode: species 5 alone touched {touched} of "
           f"{on_disk} container bytes ({touched / on_disk:.0%}) and came "
-          "back bitwise equal to the full decode's slice "
-          "(see benchmarks/bench_partial.py for the measured speedups).")
+          "back bitwise equal to the full decode's slice.")
 
     # 5. sharded encode + window query: the container above is already the
     #    time-sharded v3 layout — the latent stream is partitioned into
@@ -131,8 +125,7 @@ def main():
     lat_win = pd.latent_bytes_parsed(time_range=(4, 8))
     print(f"\nwindow query: a 4-of-16-frame window entropy-decodes "
           f"{lat_win} of {lat_full} latent chain bytes "
-          f"({lat_win / lat_full:.0%} ~ the window fraction; see "
-          "benchmarks/bench_shards.py for the full sweep). Fitting "
+          f"({lat_win / lat_full:.0%} ~ the window fraction). Fitting "
           "larger-than-memory series is the same API via time chunks: "
           "codec.GBATCCodec(cfg).fit_stream(s3d.S3DChunkLoader(...)).")
 
@@ -166,8 +159,7 @@ def main():
     assert np.array_equal(field[healthy], decoded[healthy])  # bitwise clean
     print(f"salvage decode: quarantined species {report.quarantined}, "
           f"all {len(healthy)} healthy species bitwise equal to the clean "
-          "decode (see benchmarks/bench_integrity.py for overhead + "
-          "throughput numbers).")
+          "decode.")
 
     # 7. decode service: many analysts, small queries — a scheduler thread
     #    coalesces concurrent (species, window) requests on one blob into
@@ -192,8 +184,7 @@ def main():
           "cache hit rates "
           + ", ".join(f"{tier}={stats[tier]['hit_rate']:.0%}"
                       for tier in ("head", "shard", "guarantee"))
-          + " (see benchmarks/bench_serve.py for QPS/p99 vs the serial "
-          "loop).")
+          + ".")
     os.remove(path)
 
     # 8. a second encoder family, same container: `family="attention"`
@@ -216,9 +207,7 @@ def main():
     print(f"\nattention family: CR "
           f"{data.nbytes / len(attn_blob):.1f}x at bound 1e-3 "
           f"(conv above: {data.nbytes / on_disk:.1f}x), worst species "
-          f"NRMSE {attn_per.max():.2e} — same container, same guarantee "
-          "(see benchmarks/bench_families.py for the CR-vs-bound sweep "
-          "against conv and SZ).")
+          f"NRMSE {attn_per.max():.2e} — same container, same guarantee.")
 
     # 9. mesh-sharded fit: the same pipeline over a ("data",) device mesh
     #    — DP trainer programs, a species/row-sharded guarantee engine,
@@ -228,7 +217,7 @@ def main():
     #    demo prints the per-device ingest memory high-water against the
     #    single-device total and the sharded-compress NRMSE (container
     #    byte-identity with the single-device engine is asserted in
-    #    tier-1 and in benchmarks/bench_mesh.py before any perf number).
+    #    tier-1, tests/test_mesh.py).
     #    On CPU the device count is locked at first jax init, so the demo
     #    runs in a child process with 8 forced host devices. On an
     #    accelerator this process already holds the devices and a child

@@ -8,8 +8,8 @@ asynchronously; the per-species guarantee streams entropy-decode while
 the NN decode runs, and a single batched Pallas replay applies the
 corrections. The pre-throughput-engine orchestration is retained as
 :func:`reconstruct_reference` / :func:`decompress_reference` — the fused
-path must match it **bit for bit** (asserted in tests and gating
-``benchmarks/bench_throughput.py``).
+path must match it **bit for bit** (asserted by
+``tests/test_throughput.py::TestFusedDecode``).
 """
 
 from __future__ import annotations
@@ -141,14 +141,14 @@ def reconstruct_reference(artifact: CompressedArtifact,
 
     With the default ``conv_impl="2d"`` the staged path shares the fused
     path's layer implementations, and ``reconstruct`` must match it **bit
-    for bit** — the gate asserted by the test suite and by
-    ``benchmarks/bench_throughput.py`` before any number is reported (it
-    proves the hot-path reorganization is semantically transparent).
+    for bit** — the gate asserted by
+    ``tests/test_throughput.py::TestFusedDecode`` (it proves the hot-path
+    reorganization is semantically transparent).
     ``conv_impl="xla"`` additionally retains the seed's convolution
-    lowering — the true pre-change cost profile used as the benchmark's
-    timing baseline; its output differs from the 2d formulation only by
+    lowering; its output differs from the 2d formulation only by
     float-summation reassociation inside the convolutions (ulp-level,
-    bound-checked in the benchmark)."""
+    bound-checked against the fused decode in
+    ``tests/test_throughput.py::TestFusedDecode``)."""
     cfg = artifact.cfg
     has_corr = artifact.corr_params is not None
     builder = _runtime if conv_impl == "2d" else _runtime_reference
@@ -200,7 +200,7 @@ def decompress(blob: bytes, *, species=None, time_range=None,
     Parsed container heads are served from a content-keyed bounded cache,
     so repeated (window) queries on one blob skip the head parse and every
     already-decoded stream; :func:`repro.codec.clear_decode_cache` drops
-    the memo (benchmarks use it to time cold decodes). A raise-mode
+    the memo (to time or test a cold decode). A raise-mode
     decode that hits corruption evicts the blob's cached head, and
     salvage never touches the cache — a salvaged parse can never be
     served later as a clean head.
@@ -243,5 +243,5 @@ def decompress_reference(blob: bytes, conv_impl: str = "2d") -> np.ndarray:
     deserialize with per-call Huffman table builds, then the staged
     host-chunked reconstruct. With the default ``conv_impl="2d"`` this is
     the fused path's bit-identity oracle; with ``"xla"`` it is the seed's
-    full cost profile (the throughput benchmark's timing baseline)."""
+    convolution lowering, equal to it up to summation order."""
     return reconstruct_reference(decode_artifact_reference(blob), conv_impl)

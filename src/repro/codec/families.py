@@ -89,7 +89,7 @@ def make_fused_decode(model, corr_net):
     the blocks->vectors layout change — as one function of device arrays,
     so a single jit dispatch replaces chunked host round-trips. All
     reshuffles are pure transposes; per-element arithmetic is identical to
-    the staged path (bit-identity asserted in tests and the benchmark).
+    the staged path (bit-identity asserted in tests).
     Any model exposing ``cfg.n_species`` and ``decode(params, z) ->
     (NB, S, bt, ph, pw)`` composes — both registered families do.
     """

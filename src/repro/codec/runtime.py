@@ -430,7 +430,7 @@ def clear_decode_cache() -> None:
     """Drop every decode-cache tier: memoized parsed heads (and with
     them the latent shards / guarantee artifacts their tiers hold), plus
     the Huffman decode-table memos on the cached decode runtimes.
-    Benchmarks use this to time genuinely cold decodes."""
+    Tests use it to run a genuinely cold decode."""
     _CACHE.clear()
     with _RUNTIMES_LOCK:
         runtimes = list(_RUNTIMES.values()) + list(_RUNTIMES_REF.values())

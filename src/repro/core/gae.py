@@ -43,9 +43,9 @@ bound:
   to or from the device.
 
 ``pipeline.compress`` sweeps error bounds against one fitted model, so the
-prepare cost amortizes across the sweep — that, plus the loop-free artifact
-assembly, is where the order-of-magnitude win over the per-species numpy
-oracle comes from (see ``benchmarks/bench_guarantee.py``).
+prepare cost amortizes across the sweep; that, plus the loop-free artifact
+assembly, is its gain over the per-species numpy oracle, to which
+``tests/test_gae.py::TestEngineOracleParity`` holds it byte for byte.
 
 Numerical contract: quantized coefficients, index sets, and the trimmed
 basis are bit-identical to the oracle's. The only reordering risk is fp64
@@ -176,7 +176,7 @@ class GuaranteeArtifact:
 
         ``table_cache`` memoizes Huffman decode tables across calls sharing
         a codebook; ``huffman`` overrides the coefficient decoder (the
-        codec benchmark passes :func:`entropy.huffman_decode_ref` to time
+        reference decode passes :func:`entropy.huffman_decode_ref` for
         the retained pre-change deserialize path)."""
         r = container.ContainerReader(blob)
         meta = r["meta"]

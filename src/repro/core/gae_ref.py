@@ -5,8 +5,8 @@ contract for the device-resident engine in :mod:`repro.core.gae`: float64
 throughout, per-species invocation, and per-block Python loops for artifact
 assembly and decode replay. The engine must reproduce this oracle's byte
 accounting bit-for-bit (same quantized coefficients, same index sets, same
-trimmed basis); ``benchmarks/bench_guarantee.py`` asserts exactly that while
-timing the two side by side.
+trimmed basis); ``tests/test_gae.py::TestEngineOracleParity`` asserts
+exactly that.
 
 See ``gae.py``'s module docstring for the shared mathematical derivation.
 """

@@ -1,3 +1,4 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas TPU kernels, each beside its pure-jnp oracle in ref.py. The
+# codec's are gbatc_project.py (guarantee projection, select and replay)
+# and flash_attention.py; the RWKV6, RG-LRU and block-quant kernels belong
+# to the seed's LM stack, a leaf due for deletion.

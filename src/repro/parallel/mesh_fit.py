@@ -32,7 +32,7 @@ exceed a single accelerator's memory, so the fit/compress path gains a
   kernels are per-species and per-block-row pure, so the concatenated
   CSR artifacts — and therefore the serialized container — are
   **byte-identical** to the single-device engine's (asserted in tier-1
-  and before any benchmark number). Prepared tensors stay on host and
+  and by ``chip_smoke.py --four-chips``). Prepared tensors stay on host and
   are chunk-staged per dispatch, so no single device ever holds the
   full (S, NB, D) problem.
 

@@ -256,9 +256,8 @@ class TestJaxprAuditRegressions(unittest.TestCase):
 class TestCLI(unittest.TestCase):
     def test_repo_is_clean(self):
         # the acceptance gate: zero non-baselined findings on the repo
-        # (lint + wire schema; the audit tier is exercised above and in
-        # benchmarks/bench_analysis.py)
-        self.assertEqual(cli.main(["--no-audit"]), 0)
+        # from all three tiers (lint, wire schema and the jaxpr audit)
+        self.assertEqual(cli.main([]), 0)
 
     def test_fixture_violations_exit_nonzero(self):
         self.assertEqual(

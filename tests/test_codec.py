@@ -300,6 +300,15 @@ class TestByteAccounting:
                  + bb["index"] + bb["basis"] + bb["meta"])
         assert parts == bb["total"]
 
+    def test_on_disk_size_equals_reported_total(self, blob_and_report,
+                                                tmp_path):
+        blob, rep = blob_and_report
+        path = tmp_path / "field.gbtc"
+        codec.write(path, blob)
+        assert path.stat().st_size == rep.bytes_breakdown["total"]
+        np.testing.assert_array_equal(codec.decompress(codec.read(path)),
+                                      codec.decompress(blob))
+
     def test_breakdown_matches_stream_table(self, blob_and_report):
         blob, rep = blob_and_report
         r = ContainerReader(blob)

@@ -21,6 +21,10 @@ The registry contract, exercised end to end:
   salvage semantics are unchanged.
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -254,6 +258,49 @@ class TestRuntimeIsolation:
     def test_head_cache_never_aliases_blobs(self, conv_blob, attn_blob):
         assert codec_runtime._cached_head(conv_blob) \
             is not codec_runtime._cached_head(attn_blob)
+
+
+#: the seed's LM stack: a leaf that no codec path may load
+_LM_MODULES = (
+    "repro.configs",
+    "repro.models.registry",
+    "repro.models.transformer",
+    "repro.models.whisper",
+    "repro.models.rglru",
+    "repro.models.rwkv6",
+    "repro.serve.serve_loop",
+    "repro.data.tokens",
+)
+
+_IMPORT_PROBE = """
+import sys
+from repro.codec import families
+from repro.core.pipeline import PipelineConfig
+
+fam = families.get("attention")
+fam.build_model(families.structural(PipelineConfig(family="attention")), 4)
+assert "repro.models.block_attention" in sys.modules
+print("\\n".join(sorted(sys.modules)))
+"""
+
+
+class TestImportIsolation:
+    def test_attention_family_loads_no_lm_module(self):
+        """Building the attention family in a fresh process loads the
+        encoder and its layers, and nothing of the seed's LM stack."""
+        src = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "..", "src")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (src, env.get("PYTHONPATH")) if p)
+        out = subprocess.run([sys.executable, "-c", _IMPORT_PROBE],
+                             capture_output=True, text=True, env=env,
+                             timeout=300)
+        assert out.returncode == 0, out.stderr
+        loaded = set(out.stdout.split())
+        leaked = sorted(m for m in loaded for lm in _LM_MODULES
+                        if m == lm or m.startswith(lm + "."))
+        assert not leaked, f"the attention family loaded {leaked}"
 
 
 class TestAttentionFaultSweep:

@@ -335,6 +335,26 @@ class TestSalvage:
         assert [(f.stream, f.unit) for f in rep.failures] \
             == [("guarantee", 2)]
 
+    @pytest.mark.parametrize("k", [1, 2, 4])
+    def test_k_corrupt_species_quarantined_exactly(
+        self, blob, regions, clean, k
+    ):
+        """A coefficient flip in each of species 0..k-1: salvage
+        quarantines exactly those, NaN-fills them, and returns every other
+        species bitwise equal to the clean decode."""
+        inj = self._inj(seed=5)
+        bad = blob
+        for i in range(k):
+            bad, _ = inj.flip_bit(bad,
+                                  _region(regions, f"guarantee:s{i}:coeff"))
+        field, rep = codec.decompress(bad, on_error="salvage")
+        assert rep.quarantined == list(range(k))
+        for i in range(field.shape[0]):
+            if i < k:
+                assert np.isnan(field[i]).all()
+            else:
+                assert field[i].tobytes() == clean[i].tobytes()
+
     def test_corrupt_shard_salvaged_with_damage_map(
         self, blob, regions, clean
     ):

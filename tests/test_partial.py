@@ -189,6 +189,27 @@ class TestPartialDecoder:
                 pd.decode(time_range=bad)
 
 
+@pytest.fixture(scope="module")
+def scale_report():
+    """A 12-species, 16-frame, 80x80 field at NRMSE 3e-4: enough data
+    that the shared streams (latents, decoder) no longer dominate."""
+    data = s3d.generate(s3d.S3DConfig(n_species=12, n_time=16, height=80,
+                                      width=80, seed=1))["species"]
+    cfg = PipelineConfig(ae_steps=150, corr_steps=80, conv_channels=(16, 32))
+    return codec.GBATCCodec(cfg).fit(data).compress_report(
+        target_nrmse=3e-4)[1]
+
+
+class TestBytesParsedAtScale:
+    @pytest.mark.parametrize("version", [2, 5])
+    def test_one_species_touches_under_six_tenths(self, scale_report,
+                                                  version):
+        b = codec.encode(scale_report.artifact, version=version)
+        pd = codec.PartialDecoder(b)
+        assert pd.bytes_parsed() == len(b)  # every byte has a purpose
+        assert pd.bytes_parsed(species=[0]) < 0.6 * len(b)
+
+
 class TestCorruptionIsolation:
     @pytest.fixture()
     def bad_blob(self, blob):

@@ -1,8 +1,8 @@
 """Integration tests: full GBA/GBATC pipeline on a small S3D surrogate.
 
 Kept deliberately small (few AE steps) — these check *invariants* (guarantee,
-decode consistency, accounting), not compression quality; quality runs live in
-benchmarks/bench_compression.py.
+decode consistency, accounting), not compression quality. The compression
+ratio at the paper's width is measured on the chip by the ``bench/`` cells.
 """
 
 import numpy as np

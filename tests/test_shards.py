@@ -119,15 +119,29 @@ class TestShardedEncode:
         blob_v2 = codec.encode(rep.artifact, version=2)
         assert codec.decompress(blob_v2).tobytes() == full.tobytes()
 
-    def test_parallel_and_serial_pack_identical(self, blob_and_report):
+    @pytest.mark.parametrize("n_shards", [5, 64])
+    def test_parallel_and_serial_pack_identical(self, blob_and_report,
+                                                n_shards):
         """Shard chains are pure functions of their rows — threading the
-        pack must not change a byte."""
+        pack must not change a byte, with fewer shards than the pool's
+        workers or many more."""
         _, rep = blob_and_report
         lat = rep.artifact.latent_q
-        rows = max(1, lat.shape[0] // 5)
+        rows = max(1, lat.shape[0] // n_shards)
+        assert -(-lat.shape[0] // rows) >= n_shards
         a = codec.pack_latent_stream(lat, rows, parallel=True)
         b = codec.pack_latent_stream(lat, rows, parallel=False)
         assert a == b
+
+    def test_default_shard_size_and_distinct_layouts(self, blob_and_report):
+        """The writer's default shard size is DEFAULT_SHARD_TGROUPS, and
+        each shard size writes its own bytes."""
+        _, rep = blob_and_report
+        blobs = {tg: codec.encode(rep.artifact, version=3, shard_tgroups=tg)
+                 for tg in (1, 2, 4)}
+        assert codec.encode(rep.artifact, version=3) == \
+            blobs[codec.DEFAULT_SHARD_TGROUPS]
+        assert len(set(blobs.values())) == len(blobs)
 
     def test_shard_tgroups_validation(self, blob_and_report):
         _, rep = blob_and_report
@@ -166,8 +180,12 @@ class TestShardedSlices:
         b4 = pd.latent_bytes_parsed((4, 8))
         b8 = pd.latent_bytes_parsed((4, 12))
         assert b4 < b8 < total
-        # 4 of 16 frames; allow generous slack for per-shard byte padding
-        assert b4 <= 0.5 * total
+        # 4 of 16 frames, plus slack for per-shard byte padding
+        assert b4 <= total * (4 / t + 0.2)
+        # windows from frame 0 grow monotonically up to the whole chain
+        sizes = [pd.latent_bytes_parsed((0, f)) for f in (4, 8, t)]
+        assert all(a <= b for a, b in zip(sizes, sizes[1:]))
+        assert sizes[0] <= total * (4 / t + 0.2)
         # bytes_parsed with a window shrinks below the full-blob identity
         assert pd.bytes_parsed(time_range=(4, 8)) < pd.bytes_parsed()
         assert pd.bytes_parsed() == len(blob)

@@ -6,8 +6,8 @@ The paper's headline claims, validated on the S3D surrogate:
   2. CR(GBATC) >= CR(GBA) > CR(SZ) at matched NRMSE;
   3. the tensor-correction network improves NRMSE at fixed storage;
   4. QoI (Arrhenius production rates) errors track PD errors.
-Full curves live in benchmarks/; these tests pin the *orderings* at small
-scale so they run in CI.
+These tests pin the *orderings* at small scale so they run in CI; no
+script draws the full curves.
 """
 
 import numpy as np

@@ -9,7 +9,7 @@ import pytest
 
 from repro import codec
 from repro.core import autoencoder as ae
-from repro.core import correction, entropy, gae
+from repro.core import blocking, correction, entropy, gae
 from repro.core.pipeline import GBATCPipeline, PipelineConfig, _batched
 from repro.data import s3d
 from repro.train import train_loop
@@ -167,6 +167,24 @@ class TestTrainerEngine:
         np.testing.assert_allclose(l_scan, l_stream, rtol=1e-4, atol=1e-7)
         np.testing.assert_allclose(l_scan, l_ref, rtol=1e-4, atol=1e-7)
 
+    def test_engine_tracks_reference_over_a_full_fit(self):
+        """A 150-step fit of the pipeline's model on a 12-species field:
+        the compiled engine's loss stays within 1e-3 relative of the
+        per-step reference trainer's at every step, so drift does not
+        accumulate over a fit's length."""
+        data = s3d.generate(s3d.S3DConfig(
+            n_species=12, n_time=16, height=80, width=80, seed=1))["species"]
+        cfg = PipelineConfig(conv_channels=(16, 32), ae_steps=150)
+        model = GBATCPipeline(cfg, n_species=data.shape[0]).model
+        normed, _, _ = GBATCPipeline._normalize(data)
+        blocks = blocking.to_blocks(normed, cfg.geometry)
+        kw = dict(steps=cfg.ae_steps, batch_size=cfg.batch_size, lr=cfg.lr,
+                  seed=cfg.seed)
+        _, l_eng = ae.fit(model, blocks, **kw)
+        _, l_ref = ae.fit_reference(model, blocks, **kw)
+        rel = np.abs(l_eng - l_ref) / np.maximum(np.abs(l_ref), 1e-12)
+        assert rel.max() < 1e-3
+
     def test_seed_changes_trajectory(self, tiny_model, tiny_blocks):
         _, l0 = self._fit(tiny_model, tiny_blocks, "stream", seed=0)
         _, l1 = self._fit(tiny_model, tiny_blocks, "stream", seed=7)
@@ -258,6 +276,17 @@ class TestFusedDecode:
         np.testing.assert_array_equal(
             pipe.decompress(rep.artifact), codec.decompress(blob)
         )
+
+    def test_xla_reference_decode_within_reassociation_noise(
+        self, fitted_blob
+    ):
+        """The reference decode on the seed's XLA convolutions differs
+        from the fused 2d decode only by summation order in the convs."""
+        _, _, _, blob = fitted_blob
+        fused = codec.decompress(blob)
+        seed = codec.decompress_reference(blob, conv_impl="xla")
+        scale = float(np.abs(seed).max())
+        np.testing.assert_allclose(seed, fused, rtol=0, atol=1e-4 * scale)
 
     def test_reference_and_fast_deserialize_agree(self, fitted_blob):
         _, _, _, blob = fitted_blob
